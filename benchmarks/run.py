@@ -796,19 +796,12 @@ def bench_wallclock_gap(n: int) -> None:
             src = TraceServiceTime(samples)
             backend = "trace"
         else:
-            import jax
-            import jax.numpy as jnp
+            from repro.launch.serve import build_executors
 
-            from repro.models import Model
-
-            model = Model(cfg)
-            params = model.init(jax.random.key(0))
-            fwd = jax.jit(lambda p, t, m=model: m.forward(p, t).logits)
-
-            def ex(b, fwd=fwd, params=params):
-                fwd(params, jnp.zeros((b, seq), jnp.int32)).block_until_ready()
-
-            src = LiveServiceTime({arch: ex}, warmup=1, cache=False)
+            src = LiveServiceTime(
+                build_executors([arch], seq=seq, smoke=True), warmup=1,
+                cache=False,
+            )
             backend = "live"
         parts = []
         data = {"backend": backend, "seq": seq}
@@ -1360,6 +1353,9 @@ SMOKE = False
 
 def main() -> None:
     global SMOKE
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default="")
     ap.add_argument("--n", type=int, default=1131)

@@ -1,4 +1,4 @@
-"""smollm-360m [dense] — llama-arch small [hf:HuggingFaceTB/SmolLM-135M]."""
+"""smollm-360m [dense] — llama-arch small [hf:HuggingFaceTB/SmolLM-360M]."""
 from .base import ArchConfig
 
 CONFIG = ArchConfig(
@@ -10,7 +10,7 @@ CONFIG = ArchConfig(
     n_kv_heads=5,
     d_ff=2560,
     vocab_size=49152,
-    source="hf:HuggingFaceTB/SmolLM-135M",
+    source="hf:HuggingFaceTB/SmolLM-360M",
     head_dim=64,
     tie_embeddings=True,
     rope_theta=10_000.0,

@@ -6,6 +6,11 @@ once.  Grid = (B, Hkv, S / BK) with the cache dimension innermost so the
 per-sequence valid length arrives via scalar prefetch (SMEM) and masks the
 tail block; an optional sliding window masks the head blocks.
 
+The kernel tiles a (B, Hkv, S, D) view of the (B, S, Hkv, D) cache so each
+KV block is (BK, D) in its last two dimensions, which the TPU lowering
+accepts for any Hkv (a (BK, 1, D) block of the native layout lowers only
+when Hkv == 1).  For Hkv > 1 that view is a transpose XLA materializes.
+
 Oracle: `repro.kernels.ref.decode_attention`.
 """
 from __future__ import annotations
@@ -17,14 +22,16 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .flash_attention import fit_block
+
 NEG_INF = -1e30
 
 
 def _kernel(
     lengths_ref,  # scalar prefetch (B,) int32 in SMEM
     q_ref,  # (1, 1, g, Dk)
-    k_ref,  # (1, bk, 1, Dk)
-    v_ref,  # (1, bk, 1, Dv)
+    k_ref,  # (1, 1, bk, Dk)
+    v_ref,  # (1, 1, bk, Dv)
     o_ref,  # (1, 1, g, Dv)
     acc_ref,
     m_ref,
@@ -53,8 +60,8 @@ def _kernel(
     @pl.when(live)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32)  # (g, Dk)
-        k = k_ref[0, :, 0].astype(jnp.float32)  # (bk, Dk)
-        v = v_ref[0, :, 0].astype(jnp.float32)  # (bk, Dv)
+        k = k_ref[0, 0].astype(jnp.float32)  # (bk, Dk)
+        v = v_ref[0, 0].astype(jnp.float32)  # (bk, Dv)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale  # (g, bk)
@@ -98,19 +105,20 @@ def flash_decode(
     Dv = v_cache.shape[-1]
     g = Hq // Hkv
     scale = scale if scale is not None else Dk ** -0.5
-    bk = min(block_k, S)
-    assert S % bk == 0, (S, bk)
+    bk = fit_block(S, block_k)
     nk = S // bk
 
     qr = q.reshape(B, Hkv, g, Dk)
+    kt = k_cache.transpose(0, 2, 1, 3)  # (B, Hkv, S, Dk)
+    vt = v_cache.transpose(0, 2, 1, 3)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(B, Hkv, nk),
         in_specs=[
             pl.BlockSpec((1, 1, g, Dk), lambda b, h, ki, lens: (b, h, 0, 0)),
-            pl.BlockSpec((1, bk, 1, Dk), lambda b, h, ki, lens: (b, ki, h, 0)),
-            pl.BlockSpec((1, bk, 1, Dv), lambda b, h, ki, lens: (b, ki, h, 0)),
+            pl.BlockSpec((1, 1, bk, Dk), lambda b, h, ki, lens: (b, h, ki, 0)),
+            pl.BlockSpec((1, 1, bk, Dv), lambda b, h, ki, lens: (b, h, ki, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, g, Dv), lambda b, h, ki, lens: (b, h, 0, 0)),
         scratch_shapes=[
@@ -127,5 +135,5 @@ def flash_decode(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
-    )(lengths.astype(jnp.int32), qr, k_cache, v_cache)
+    )(lengths.astype(jnp.int32), qr, kt, vt)
     return out.reshape(B, Hq, Dv)

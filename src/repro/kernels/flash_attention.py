@@ -21,6 +21,17 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
+def fit_block(n: int, block: int) -> int:
+    """The block that tiles a length-``n`` axis: ``n`` itself when it is at
+    most ``block``, else the largest halving of ``block`` dividing ``n``.
+    The TPU lowering takes a block equal to the whole axis or a multiple of
+    8, so ``n <= block or n % 8 == 0`` is what a caller must ensure."""
+    b = min(block, n)
+    while n % b:
+        b //= 2
+    return b
+
+
 def _kernel(
     q_ref,
     k_ref,
@@ -108,9 +119,8 @@ def flash_attention(
     Dv = v.shape[-1]
     g = Hq // Hkv
     scale = scale if scale is not None else Dk ** -0.5
-    bq = min(block_q, Sq)
-    bk = min(block_k, Sk)
-    assert Sq % bq == 0 and Sk % bk == 0, (Sq, Sk, bq, bk)
+    bq = fit_block(Sq, block_q)
+    bk = fit_block(Sk, block_k)
     nk = Sk // bk
 
     qr = q.transpose(0, 2, 1, 3).reshape(B * Hq, Sq, Dk)

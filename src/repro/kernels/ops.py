@@ -6,6 +6,7 @@ model code identical across CPU validation and TPU deployment.
 """
 from __future__ import annotations
 
+import collections
 import contextlib
 import contextvars
 import functools
@@ -23,6 +24,24 @@ def _mode() -> str:
     if forced in ("interpret", "tpu"):
         return forced
     return "tpu" if jax.default_backend() == "tpu" else "ref"
+
+
+# (op, path) -> times traced.  Recorded at trace time, so a run can show
+# which implementation each op took at the shapes it compiled.
+TAKEN: collections.Counter = collections.Counter()
+
+
+def _take(op: str, kernel_ok: bool) -> str:
+    """The path ``op`` takes ("tpu", "interpret" or "ref"), counted in TAKEN."""
+    mode = _mode() if kernel_ok else "ref"
+    TAKEN[op, mode] += 1
+    return mode
+
+
+def _tileable(n: int, block: int) -> bool:
+    """Whether a kernel can tile a length-``n`` axis with `fit_block`
+    (blocks equal to the axis, or multiples of 8, are what TPU lowers)."""
+    return n <= block or n % 8 == 0
 
 
 @dataclass(frozen=True)
@@ -105,7 +124,6 @@ def row_parallel_dense(x, w):
         or x.shape[-1] != f
     ):
         return x @ w.astype(x.dtype)
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     dp = ctx.dp_axes if len(ctx.dp_axes) > 1 else ctx.dp_axes[0]
@@ -116,9 +134,9 @@ def row_parallel_dense(x, w):
     def body(xx, ww):
         return jax.lax.psum(xx @ ww.astype(xx.dtype), ctx.model_axis)
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=ctx.mesh, in_specs=(x_spec, w_spec), out_specs=out_spec,
-        check_rep=False,
+        check_vma=False,
     )(x, w)
 
 
@@ -141,7 +159,6 @@ def _sharded_attention(ctx: MeshCtx, q, k, v, *, causal, window, scale):
     When KV heads do not divide the model axis (MQA), KV is replicated across
     model ranks and each rank serves its local query-head group.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     dp = ctx.dp_axes if len(ctx.dp_axes) > 1 else ctx.dp_axes[0]
@@ -157,19 +174,19 @@ def _sharded_attention(ctx: MeshCtx, q, k, v, *, causal, window, scale):
             )
         return ref.attention(qq, kk, vv, causal=causal, window=window, scale=scale)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=ctx.mesh,
         in_specs=(q_spec, kv_spec, kv_spec),
         out_specs=q_spec,
-        check_rep=False,
+        check_vma=False,
     )
     return fn(q, k, v)
 
 
 def attention(q, k, v, *, causal=True, window=None, scale=None, q_offset=0, kv_len=None):
-    mode = _mode()
-    if mode != "ref" and kv_len is None and q.shape[1] % 128 == 0:
+    mode = _take("attention", kv_len is None and _tileable(q.shape[1], 128))
+    if mode != "ref":
         from .flash_attention import flash_attention
 
         return flash_attention(
@@ -227,25 +244,24 @@ def mla_prefill_attention(q_nope, q_rope, k_nope, kr, v, *, scale):
 
     ctx = _MESH_CTX.get()
     if ctx is not None and B % ctx.dp_size == 0 and H % ctx.model_size == 0:
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         dp = ctx.dp_axes if len(ctx.dp_axes) > 1 else ctx.dp_axes[0]
         hspec = P(dp, None, ctx.model_axis, None)
-        fn = shard_map(
+        fn = jax.shard_map(
             body,
             mesh=ctx.mesh,
             in_specs=(hspec, hspec, hspec, P(dp, None, None), hspec),
             out_specs=hspec,
-            check_rep=False,
+            check_vma=False,
         )
         return fn(q_nope, q_rope, k_nope, kr, v)
     return body(q_nope, q_rope, k_nope, kr, v)
 
 
 def decode_attention(q, k_cache, v_cache, lengths, *, window=None, scale=None):
-    mode = _mode()
-    if mode != "ref" and k_cache.shape[1] % 128 == 0:
+    mode = _take("decode_attention", _tileable(k_cache.shape[1], 512))
+    if mode != "ref":
         from .decode_attention import flash_decode
 
         return flash_decode(
@@ -261,8 +277,8 @@ def decode_attention(q, k_cache, v_cache, lengths, *, window=None, scale=None):
 
 
 def rmsnorm(x, w, *, eps=1e-6, gemma=False):
-    mode = _mode()
-    if mode != "ref" and x.shape[-1] % 128 == 0:
+    mode = _take("rmsnorm", True)
+    if mode != "ref":
         from .rmsnorm import fused_rmsnorm
 
         return fused_rmsnorm(x, w, eps=eps, gemma=gemma, interpret=mode == "interpret")
@@ -270,8 +286,8 @@ def rmsnorm(x, w, *, eps=1e-6, gemma=False):
 
 
 def selective_scan(x, dt, A, Bm, Cm, h0=None):
-    mode = _mode()
-    if mode != "ref" and x.shape[1] % 128 == 0:
+    mode = _take("selective_scan", x.shape[1] % 128 == 0)
+    if mode != "ref":
         from .ssm_scan import chunked_selective_scan
 
         return chunked_selective_scan(x, dt, A, Bm, Cm, h0, interpret=mode == "interpret")
@@ -279,8 +295,8 @@ def selective_scan(x, dt, A, Bm, Cm, h0=None):
 
 
 def mlstm(q, k, v, i_gate, f_gate, *, chunk=128):
-    mode = _mode()
-    if mode != "ref" and q.shape[1] % chunk == 0:
+    mode = _take("mlstm", q.shape[1] % chunk == 0)
+    if mode != "ref":
         from .mlstm_chunk import chunked_mlstm
 
         return chunked_mlstm(

@@ -50,7 +50,7 @@ def _kernel(
         a = jnp.exp(dt[None, :] * at)  # (N, D)
         h = a * h + (dt * x)[None, :] * bt[:, None]
         y = jnp.sum(h * ct[:, None], axis=0)  # (D,)
-        pl.store(y_ref, (0, pl.dslice(t, 1), pl.dslice(None)), y[None].astype(y_ref.dtype))
+        y_ref[0, pl.ds(t, 1), :] = y[None].astype(y_ref.dtype)
         return h
 
     h = jax.lax.fori_loop(0, cl, step, h_ref[:])

@@ -12,16 +12,18 @@ the partitioned HLO text: operand sizes of all-gather / all-reduce /
 reduce-scatter / all-to-all / collective-permute ops, with a ring-algorithm
 wire factor (2x for all-reduce, 1x otherwise).
 
-Hardware constants (TPU v5e): 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link.
+Hardware constants: the TPU v5e entry of `repro.profiling.hardware`.
 """
 from __future__ import annotations
 
 import re
 from dataclasses import dataclass, field
 
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
-LINK_BW = 50e9
+from ..profiling.hardware import TPU_V5E
+
+PEAK_FLOPS = TPU_V5E.peak_flops_bf16
+HBM_BW = TPU_V5E.hbm_bw
+LINK_BW = TPU_V5E.ici_bw
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,

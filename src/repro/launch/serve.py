@@ -1,9 +1,14 @@
 """Serving launcher: plan with Harpagon, then serve batched requests.
 
 Plans a (possibly multi-module) session over the analytic TPU profiles and
-runs the serving engine.  With --real, module executors are real jitted JAX
-forwards of reduced models on CPU; otherwise profiled durations drive an
-event simulation at full scale.
+runs the serving engine.  With --real, every batch is a jitted forward of
+the module at published widths on the attached TPU (seeded random weights
+and token ids), planned on that chip's profile only; --real --smoke runs
+the reduced configs instead, which is how CPU tests drive the path.
+Without --real, profiled durations drive an event simulation.
+
+  python -m repro.launch.serve --arch smollm-360m,gemma3-1b --real \
+      --pipeline --seq 128            # the chip path (`chip_smoke.py`)
 
   PYTHONPATH=src python -m repro.launch.serve --arch smollm-360m \
       --rate 200 --slo 0.5 --requests 2000
@@ -24,19 +29,87 @@ event simulation at full scale.
 from __future__ import annotations
 
 import argparse
+import time
+from dataclasses import dataclass
 
 import jax
 import jax.numpy as jnp
 
-from ..configs import get_config
+from ..configs import ArchConfig, get_config
 from ..core import Leaf, Workload, series
 from ..core.baselines import ALL_SYSTEMS
 from ..core.dag import AppDAG
-from ..core.harpagon import Planner
+from ..core.harpagon import Plan, Planner
 from ..models import Model
-from ..profiling import arch_profile
-from ..serving import ControlLoopConfig, FaultConfig, ServingEngine, SharedPool
+from ..profiling import CATALOG, arch_profile, spec_for
+from ..serving import (
+    ControlLoopConfig,
+    FaultConfig,
+    LiveServiceTime,
+    ServingEngine,
+    SharedPool,
+)
 from ..serving.arrivals import trace_arrivals
+from .compile_cache import enable_compile_cache
+
+
+class ModuleExecutor:
+    """One module's jitted forward over seeded random token ids.
+
+    ``ex(b)`` runs a ``(b, seq)`` batch on the default device and blocks
+    until its logits are ready (the `ServingEngine` executor contract), and
+    returns them.  The first call at a batch size compiles it ahead of the
+    run and records the compile seconds in ``compile_s`` (set-up, never a
+    step).
+    """
+
+    def __init__(self, cfg: ArchConfig, *, seq: int):
+        self.cfg = cfg
+        self.seq = seq
+        model = Model(cfg)
+        self.params = jax.jit(model.init)(jax.random.key(0))
+        self._fwd = jax.jit(lambda p, t: model.forward(p, t).logits)
+        self._tok_key = jax.random.key(1)
+        self.compiled: dict[int, object] = {}
+        self.compile_s: dict[int, float] = {}
+        self._tokens: dict[int, jax.Array] = {}
+
+    def __call__(self, b: int) -> jax.Array:
+        if b not in self.compiled:
+            toks = jax.random.randint(
+                jax.random.fold_in(self._tok_key, b),
+                (b, self.seq), 0, self.cfg.vocab_size, jnp.int32,
+            )
+            t0 = time.perf_counter()
+            self.compiled[b] = self._fwd.lower(self.params, toks).compile()
+            self.compile_s[b] = time.perf_counter() - t0
+            self._tokens[b] = toks
+        return self.compiled[b](self.params, self._tokens[b]).block_until_ready()
+
+
+def build_executors(
+    archs, *, seq: int, smoke: bool = False
+) -> dict[str, ModuleExecutor]:
+    """Executors for a chain of archs (published widths unless ``smoke``)."""
+    return {a: ModuleExecutor(get_config(a, smoke=smoke), seq=seq) for a in archs}
+
+
+def plan_batches(plan: Plan) -> dict[str, tuple[int, ...]]:
+    """The batch sizes each module's allocations run at."""
+    return {
+        m: tuple(sorted({a.config.batch for a in s.allocs}))
+        for m, s in plan.schedules.items()
+    }
+
+
+@dataclass
+class ServeRun:
+    """What one engine run of `main` produced (None for --pool/--compare)."""
+
+    plan: Plan
+    result: object  # ServeResult
+    executors: dict[str, ModuleExecutor]
+    live: "LiveServiceTime | None"
 
 
 def _make_faults(args) -> "FaultConfig | None":
@@ -105,14 +178,24 @@ def _serve_pool(args, archs, profiles) -> None:
         print(f"wrote {len(res.trace.events())} pool trace events to {path}")
 
 
-def main() -> None:
+def main(argv=None) -> "ServeRun | None":
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True, help="comma-separated chain of archs")
     ap.add_argument("--rate", type=float, default=100.0)
     ap.add_argument("--slo", type=float, default=1.0)
     ap.add_argument("--requests", type=int, default=2000)
     ap.add_argument("--seq", type=int, default=128)
-    ap.add_argument("--real", action="store_true", help="execute reduced models on CPU")
+    ap.add_argument(
+        "--real", action="store_true",
+        help="execute every batch as a jitted forward at published widths on "
+        "the attached TPU, planned on that chip's profile (fails elsewhere "
+        "unless --smoke)",
+    )
+    ap.add_argument(
+        "--smoke", action="store_true",
+        help="reduced configs (with --real: runs on CPU, for tests)",
+    )
     ap.add_argument("--compare", action="store_true", help="plan with all 5 systems")
     ap.add_argument(
         "--pipeline", action="store_true",
@@ -154,7 +237,7 @@ def main() -> None:
         "Perfetto trace-event JSON to PATH (default trace.json) — load it "
         "at https://ui.perfetto.dev",
     )
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     if args.epoch and not args.pipeline:
         ap.error("--epoch requires --pipeline (the control loop lives in "
                  "the pipelined serving loop)")
@@ -163,13 +246,30 @@ def main() -> None:
                  "the pipelined serving loop)")
 
     archs = args.arch.split(",")
-    profiles = {a: arch_profile(get_config(a), seq=args.seq) for a in archs}
+    hardware = tuple(CATALOG)
+    if args.real:
+        if jax.default_backend() == "tpu":
+            dev = jax.devices()[0]
+            hardware = (spec_for(dev).name,)
+            print(f"device: {dev.platform} {dev.device_kind!r} "
+                  f"x{len(jax.devices())} -> {hardware[0]}")
+        elif not args.smoke:
+            raise SystemExit(
+                f"--real serves published widths on a TPU; the backend is "
+                f"{jax.default_backend()!r} (use --smoke on CPU)"
+            )
+    profiles = {
+        a: arch_profile(
+            get_config(a, smoke=args.smoke), seq=args.seq, hardware=hardware
+        )
+        for a in archs
+    }
 
     if args.pool:
         if args.compare:
             ap.error("--pool and --compare are mutually exclusive")
         _serve_pool(args, archs, profiles)
-        return
+        return None
 
     dag = AppDAG("session", series(*[Leaf(a) for a in archs]))
     wl = Workload(dag, {a: args.rate for a in archs}, args.slo)
@@ -178,7 +278,7 @@ def main() -> None:
         for opts in ALL_SYSTEMS:
             plan = Planner(opts).plan(wl, profiles)
             print(plan.summary())
-        return
+        return None
 
     plan = Planner().plan(wl, profiles)
     print(plan.summary())
@@ -187,20 +287,18 @@ def main() -> None:
 
     faults = _make_faults(args)
 
-    executors = {}
+    executors: dict[str, ModuleExecutor] = {}
+    live = None
     if args.real:
-        for a in archs:
-            cfg = get_config(a, smoke=True)
-            model = Model(cfg)
-            params = model.init(jax.random.key(0))
-            fwd = jax.jit(lambda p, t, m=model: m.forward(p, t).logits)
-
-            def ex(b, fwd=fwd, params=params, cfg=cfg):
-                toks = jnp.zeros((b, 32), jnp.int32)
-                fwd(params, toks).block_until_ready()
-
-            ex(1)  # warm the jit cache
-            executors[a] = ex
+        executors = build_executors(archs, seq=args.seq, smoke=args.smoke)
+        # set-up: compile and run once every batch size the plan uses
+        for m, bs in plan_batches(plan).items():
+            for b in bs:
+                executors[m](b)
+                print(f"setup: compiled {m} b{b} in "
+                      f"{executors[m].compile_s[b]:.3f}s")
+        if args.pipeline:
+            live = LiveServiceTime(executors)
 
     engine = ServingEngine(plan, executors=executors)
     control = (
@@ -223,7 +321,7 @@ def main() -> None:
         arrivals=arrivals,
         pipeline=args.pipeline,
         control=control,
-        service_time="live" if (args.real and args.pipeline) else None,
+        service_time=live,
         observability=args.trace is not None,
         faults=faults,
     )
@@ -267,6 +365,7 @@ def main() -> None:
                 f"wrote {n_ev} trace events to {path} "
                 f"(load at https://ui.perfetto.dev)"
             )
+    return ServeRun(plan, res, executors, live)
 
 
 if __name__ == "__main__":

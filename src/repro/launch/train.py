@@ -18,9 +18,11 @@ from ..configs import get_config
 from ..data import lm_batches
 from ..models import Model
 from ..training import OptConfig, save, train
+from .compile_cache import enable_compile_cache
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true", help="reduced config (CPU)")

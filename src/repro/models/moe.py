@@ -205,7 +205,6 @@ def _moe_shard_mapped(
     expert dim; the router is replicated.  Token counts that do not divide
     the EP degree (e.g. single-token decode) are zero-padded.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     N, d = flat.shape
@@ -225,12 +224,12 @@ def _moe_shard_mapped(
         P(tok_t, None),
     )
     body = lambda pp, xx: expert_ffn_ep(pp, cfg, xx, info)
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=info.mesh,
         in_specs=in_specs,
         out_specs=(P(tok_t, None), P()),
-        check_rep=False,
+        check_vma=False,
     )
     y, aux = fn(p_ep, flat)
     if pad:

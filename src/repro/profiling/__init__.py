@@ -5,7 +5,7 @@ from .analytic import (
     module_duration,
     param_count,
 )
-from .hardware import CATALOG, TARGET, TPUSpec
+from .hardware import CATALOG, TPUSpec, spec_for
 from .interference import InterferenceModel, calibrate as calibrate_interference
 from .measured import (
     corrected_profile,
@@ -15,8 +15,8 @@ from .measured import (
 )
 
 __all__ = [
-    "CATALOG", "InterferenceModel", "TARGET", "TPUSpec", "arch_profile",
+    "CATALOG", "InterferenceModel", "TPUSpec", "arch_profile",
     "calibrate_interference", "corrected_profile", "corrected_profiles",
     "duration_scale", "flops_per_token", "kv_cache_bytes_per_token",
-    "module_duration", "param_count", "quantize_scale",
+    "module_duration", "param_count", "quantize_scale", "spec_for",
 ]

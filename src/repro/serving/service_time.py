@@ -17,7 +17,7 @@ against measured executor step times:
   ``crc32(module) ^ batch`` so replay order across modules cannot leak
   randomness between keys.
 * :class:`LiveServiceTime` — actual executor forwards
-  (``executors[module](batch_size)``, e.g. the jitted reduced-model
+  (``executors[module](batch_size)``, e.g. the jitted published-width
   forwards of ``repro.launch.serve --real``), timed with
   ``time.perf_counter`` per batch start and cached per ``(module, batch)``
   once ``warmup`` timed calls have retired the jit/compile transient.
@@ -229,8 +229,10 @@ class LiveServiceTime(ServiceTimeSource):
     The first ``warmup`` timed calls of a key are treated as the jit/compile
     transient; once a key has ``warmup + 1`` measurements, the mean of the
     post-warmup ones is cached and returned without re-executing (the
-    co-simulation then advances at recorded wall-clock speed).  Modules
-    without an executor fall back to the profiled duration.  ``cache=False``
+    co-simulation then advances at recorded wall-clock speed).  A module
+    without an executor is an error, never the profiled duration: a live
+    run that silently mixed measured and modeled times would report the
+    model as a measurement.  ``cache=False``
     re-measures every batch (honest but slow — every simulated batch is a
     real forward).
     """
@@ -263,7 +265,10 @@ class LiveServiceTime(ServiceTimeSource):
             return hit
         ex = self.executors.get(module)
         if ex is None:
-            return machine.config.duration
+            raise KeyError(
+                f"live service time: no executor for module {module!r} "
+                f"(executors: {sorted(self.executors)})"
+            )
         t0 = time.perf_counter()
         ex(b)
         d = time.perf_counter() - t0

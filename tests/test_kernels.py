@@ -7,27 +7,9 @@ import pytest
 # JAX-compile-heavy (jits real kernels/models); deselect with -m "not slow"
 pytestmark = pytest.mark.slow
 
-# The kernels target the Pallas TPU API surface they were written against
-# (`pltpu.CompilerParams`); jax builds predating or renaming that surface
-# (older builds call it `TPUCompilerParams`) fail every kernel call with an
-# AttributeError.  That is an environment capability gap, not a kernel
-# regression — skip the whole module rather than fail 30+ parametrizations.
-try:
-    from jax.experimental.pallas import tpu as _pltpu
-
-    _has_pallas_surface = hasattr(_pltpu, "CompilerParams")
-except ImportError:  # pragma: no cover - env-dependent
-    _has_pallas_surface = False
-if not _has_pallas_surface:
-    pytest.skip(
-        "Pallas TPU kernel surface (pltpu.CompilerParams) unavailable in "
-        "this environment's jax build",
-        allow_module_level=True,
-    )
-
-from repro.kernels import ref
+from repro.kernels import ops, ref
 from repro.kernels.decode_attention import flash_decode
-from repro.kernels.flash_attention import flash_attention
+from repro.kernels.flash_attention import fit_block, flash_attention
 from repro.kernels.rmsnorm import fused_rmsnorm
 from repro.kernels.ssm_scan import chunked_selective_scan
 
@@ -174,3 +156,38 @@ def test_chunked_mlstm_sweep(B, L, H, D, chunk, dtype):
     out = chunked_mlstm(q, k, v, li, lf, chunk=chunk, interpret=True)
     exp = ref.mlstm_chunked(q, k, v, li, lf)
     assert rel_err(out, exp) < (3e-2 if dtype == jnp.bfloat16 else 2e-4)
+
+
+@pytest.mark.parametrize(
+    "n,block,expect",
+    [(128, 128, 128), (64, 128, 64), (7, 128, 7), (1024, 512, 512),
+     (640, 512, 128), (200, 128, 8)],
+)
+def test_fit_block(n, block, expect):
+    """The block tiles the axis and is the whole axis or a multiple of 8."""
+    b = fit_block(n, block)
+    assert b == expect and n % b == 0
+
+
+def test_rmsnorm_takes_kernel_at_unaligned_width(monkeypatch):
+    """Width 960 (smollm) runs the fused kernel, not the `ref` fallback."""
+    monkeypatch.setattr(ops, "_mode", lambda: "interpret")
+    monkeypatch.setattr(ops, "TAKEN", type(ops.TAKEN)())
+    x = jax.random.normal(jax.random.key(8), (2, 8, 960), jnp.bfloat16)
+    w = jax.random.normal(jax.random.key(9), (960,), jnp.bfloat16)
+    out = ops.rmsnorm(x, w)
+    assert dict(ops.TAKEN) == {("rmsnorm", "interpret"): 1}
+    assert rel_err(out, ref.rmsnorm(x, w)) < TOL[jnp.bfloat16]
+
+
+def test_flash_decode_multi_kv_heads_untiled_length():
+    """Hkv > 1 over a cache whose length is not a multiple of block_k."""
+    B, S, Hq, Hkv, D = 2, 640, 6, 3, 64
+    ks = jax.random.split(jax.random.key(10), 3)
+    q = jax.random.normal(ks[0], (B, Hq, D))
+    kc = jax.random.normal(ks[1], (B, S, Hkv, D))
+    vc = jax.random.normal(ks[2], (B, S, Hkv, D))
+    lengths = jnp.asarray([300, 640], jnp.int32)
+    out = flash_decode(q, kc, vc, lengths, interpret=True)
+    exp = ref.decode_attention(q, kc, vc, lengths)
+    assert rel_err(out, exp) < TOL[jnp.float32]

@@ -1,4 +1,5 @@
 """MoE: routing, local ragged path vs explicit per-expert loop, EP shard_map."""
+import os
 import subprocess
 import sys
 import textwrap
@@ -71,7 +72,6 @@ _EP_SCRIPT = textwrap.dedent(
     sys.path.insert(0, "src")
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import Mesh, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
     from repro.configs import SMOKE_ARCHS
     from repro.models.moe import MoEMeshInfo, moe_forward, moe_init
 
@@ -103,6 +103,7 @@ def test_ep_shard_map_matches_local_8_devices():
         text=True,
         timeout=300,
         cwd=".",
+        env={**os.environ, "JAX_PLATFORMS": "cpu"},
     )
     assert r.returncode == 0, r.stderr[-2000:]
     assert "EP-OK" in r.stdout

@@ -9,7 +9,7 @@ from repro.profiling import (
     module_duration,
     param_count,
 )
-from repro.profiling.hardware import CATALOG, TPU_V5E
+from repro.profiling.hardware import CATALOG, TPU_V4, TPU_V5E, TPU_V5P, spec_for
 
 # published total / active parameter counts (billions)
 PUBLISHED = {
@@ -77,3 +77,23 @@ def test_flops_per_token_decode_vs_prefill():
     assert flops_per_token(cfg, 32768, decode=True) > flops_per_token(
         cfg, 32768, decode=False
     )
+
+
+@pytest.mark.parametrize(
+    "kind,spec",
+    [("TPU v5 lite", TPU_V5E), ("TPU v4", TPU_V4), ("TPU v5", TPU_V5P)],
+)
+def test_spec_for_device_kind(kind, spec):
+    """The peaks table is keyed by the device_kind JAX reports."""
+
+    class Dev:
+        device_kind = kind
+
+    assert spec_for(kind) is spec
+    assert spec_for(Dev()) is spec
+
+
+@pytest.mark.parametrize("kind", ["cpu", "TPU v6 lite", ""])
+def test_spec_for_unknown_kind_raises(kind):
+    with pytest.raises(KeyError, match="no peaks"):
+        spec_for(kind)

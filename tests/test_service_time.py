@@ -133,8 +133,10 @@ class TestLiveBackend:
         assert ("m", 8) in src.measured
 
     def test_no_executor_falls_back_to_profile(self):
+        """A module with no executor is an error: no silent profiled time."""
         src = LiveServiceTime({"other": lambda b: None})
-        assert src.duration("m", _machine(duration=0.07), 8) == pytest.approx(0.07)
+        with pytest.raises(KeyError, match="no executor"):
+            src.duration("m", _machine(duration=0.07), 8)
 
     def test_to_trace_freezes_post_warmup(self):
         src = LiveServiceTime({"m": lambda b: None}, warmup=1, cache=False)

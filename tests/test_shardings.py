@@ -1,4 +1,5 @@
 """Sharding rules + an 8-device end-to-end sharded train/decode (subprocess)."""
+import os
 import subprocess
 import sys
 import textwrap
@@ -106,6 +107,7 @@ def test_sharded_train_and_decode_8_devices():
         text=True,
         timeout=560,
         cwd=".",
+        env={**os.environ, "JAX_PLATFORMS": "cpu"},
     )
     assert r.returncode == 0, r.stderr[-3000:]
     assert "SHARDED-TRAIN-OK" in r.stdout
