@@ -1,0 +1,9 @@
+"""Device: the share of the traced chunk in which no operation ran on the
+chip, from the profiler's trace (moves ``served_rps``)."""
+
+
+def read(run):
+    t = run.trace
+    if t is None or t.window_s <= 0 or t.busy_s <= 0:
+        return None
+    return 100.0 * (1.0 - t.busy_s / t.window_s)
