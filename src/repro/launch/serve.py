@@ -29,6 +29,7 @@ Without --real, profiled durations drive an event simulation.
 from __future__ import annotations
 
 import argparse
+import re
 import time
 from dataclasses import dataclass
 
@@ -50,7 +51,23 @@ from ..serving import (
     SharedPool,
 )
 from ..serving.arrivals import trace_arrivals
+from ..serving.observability import span
 from .compile_cache import enable_compile_cache
+
+
+def _named_forward(model: Model, name: str):
+    """The module's forward as a function named for it, its body under
+    ``jax.named_scope(name)``: the compiled executable (the device trace's
+    XLA Modules line) and its ops' metadata then say which module ran."""
+
+    def forward(params, tokens):
+        with jax.named_scope(name):
+            return model.forward(params, tokens).logits
+
+    forward.__name__ = forward.__qualname__ = (
+        "forward_" + re.sub(r"\W", "_", name)
+    )
+    return forward
 
 
 class ModuleExecutor:
@@ -60,15 +77,21 @@ class ModuleExecutor:
     until its logits are ready (the `ServingEngine` executor contract), and
     returns them.  The first call at a batch size compiles it ahead of the
     run and records the compile seconds in ``compile_s`` (set-up, never a
-    step).
+    step).  Each later call is two spans (`serving.observability.spans`):
+    ``dispatch <name> b<b>`` while the host enqueues the compiled forward,
+    and ``sync <name> b<b>`` while it waits for the logits.  ``name`` is
+    ``cfg.name``: a module served by this executor must carry the arch's
+    name, since its spans land on that module's registry row
+    (`LiveServiceTime` refuses an executor bound to another name).
     """
 
     def __init__(self, cfg: ArchConfig, *, seq: int):
         self.cfg = cfg
         self.seq = seq
+        self.name = cfg.name
         model = Model(cfg)
         self.params = jax.jit(model.init)(jax.random.key(0))
-        self._fwd = jax.jit(lambda p, t: model.forward(p, t).logits)
+        self._fwd = jax.jit(_named_forward(model, self.name))
         self._tok_key = jax.random.key(1)
         self.compiled: dict[int, object] = {}
         self.compile_s: dict[int, float] = {}
@@ -84,7 +107,10 @@ class ModuleExecutor:
             self.compiled[b] = self._fwd.lower(self.params, toks).compile()
             self.compile_s[b] = time.perf_counter() - t0
             self._tokens[b] = toks
-        return self.compiled[b](self.params, self._tokens[b]).block_until_ready()
+        with span("dispatch", self.name, b):
+            out = self.compiled[b](self.params, self._tokens[b])
+        with span("sync", self.name, b):
+            return out.block_until_ready()
 
 
 def build_executors(

@@ -62,7 +62,7 @@ from .faults import FaultConfig, FaultRuntime
 from .frontend import FrontendConfig, make_admission
 from .frontend.clients import closed_loop_ingress
 from .frontend.dummy import merge_phantoms, phantom_times
-from .observability import Observability
+from .observability import Observability, active, annotate
 from .replay import (
     ModuleReplay,
     causal_order,
@@ -360,6 +360,11 @@ class ServingEngine:
         force-replans the failed module out-of-band.  A disabled config
         (neither ``mtbf`` nor ``schedule`` set) is treated exactly like
         ``faults=None`` — bit-exact with the injector absent.
+
+        The run is a ``serve`` annotation on the profiler's clock (see
+        `observability.spans`); while ``observability`` is on, its runtime
+        is the one the served path's spans feed, and each garbage
+        collection is spanned, until the run returns.
         """
         fe = frontend or FrontendConfig()
         obs = Observability.make(observability)
@@ -383,56 +388,57 @@ class ServingEngine:
                 "the flat path replays whole modules and has no machines to "
                 "fail mid-run"
             )
-        src = resolve_service_time(service_time, self.executors)
-        if pipeline:
-            return self._run_pipeline(
-                n_frames, frame_rate, fe, ctrl,
-                arrivals=arrivals, seed=seed, timeout=timeout, tail=tail,
-                offered_rate=offered_rate, cfg=pipeline, control=control,
-                service_time=src, obs=obs, faults=faults,
+        with annotate("serve"), active(obs):
+            src = resolve_service_time(service_time, self.executors)
+            if pipeline:
+                return self._run_pipeline(
+                    n_frames, frame_rate, fe, ctrl,
+                    arrivals=arrivals, seed=seed, timeout=timeout, tail=tail,
+                    offered_rate=offered_rate, cfg=pipeline, control=control,
+                    service_time=src, obs=obs, faults=faults,
+                )
+            if fe.clients is not None:
+                warnings.warn(
+                    "the fixed-point closed loop (clients= without pipeline=True) "
+                    "is deprecated: the event-interleaved co-simulation "
+                    "(pipeline=True) replaces the latency-oracle iteration",
+                    DeprecationWarning,
+                    stacklevel=2,
+                )
+                return self._run_closed_loop(
+                    n_frames, frame_rate, fe, ctrl,
+                    seed=seed, timeout=timeout, tail=tail,
+                    offered_rate=offered_rate,
+                )
+            arrival = make_arrivals(
+                arrivals, n_frames,
+                offered_rate if offered_rate is not None else frame_rate,
+                seed=seed,
             )
-        if fe.clients is not None:
-            warnings.warn(
-                "the fixed-point closed loop (clients= without pipeline=True) "
-                "is deprecated: the event-interleaved co-simulation "
-                "(pipeline=True) replaces the latency-oracle iteration",
-                DeprecationWarning,
-                stacklevel=2,
+            if ctrl is not None:
+                ctrl.reset()
+                ctrl.obs = obs  # flat path: ingress sheds land in the telemetry
+                shed_mask = ctrl.shed_stream(arrival)
+            else:
+                shed_mask = np.zeros(n_frames, dtype=bool)
+            result, lat = self._serve(
+                arrival, shed_mask, frame_rate, fe, timeout=timeout, tail=tail,
+                service_time=src, obs=obs,
             )
-            return self._run_closed_loop(
-                n_frames, frame_rate, fe, ctrl,
-                seed=seed, timeout=timeout, tail=tail,
-                offered_rate=offered_rate,
-            )
-        arrival = make_arrivals(
-            arrivals, n_frames,
-            offered_rate if offered_rate is not None else frame_rate,
-            seed=seed,
-        )
-        if ctrl is not None:
-            ctrl.reset()
-            ctrl.obs = obs  # flat path: ingress sheds land in the telemetry
-            shed_mask = ctrl.shed_stream(arrival)
-        else:
-            shed_mask = np.zeros(n_frames, dtype=bool)
-        result, lat = self._serve(
-            arrival, shed_mask, frame_rate, fe, timeout=timeout, tail=tail,
-            service_time=src, obs=obs,
-        )
-        if obs is not None:
-            fin = arrival + lat
-            t_end = (
-                float(np.nanmax(fin))
-                if np.isfinite(fin).any()
-                else (float(arrival.max()) if arrival.size else 0.0)
-            )
-            machines_of = {
-                m: len(expand_machines(list(s.allocs)))
-                for m, s in self.plan.schedules.items()
-            }
-            result.metrics = obs.finalize(t_end, machines_of)
-            result.trace = obs.trace
-        return result
+            if obs is not None:
+                fin = arrival + lat
+                t_end = (
+                    float(np.nanmax(fin))
+                    if np.isfinite(fin).any()
+                    else (float(arrival.max()) if arrival.size else 0.0)
+                )
+                machines_of = {
+                    m: len(expand_machines(list(s.allocs)))
+                    for m, s in self.plan.schedules.items()
+                }
+                result.metrics = obs.finalize(t_end, machines_of)
+                result.trace = obs.trace
+            return result
 
     def _run_closed_loop(
         self,
@@ -822,11 +828,17 @@ class ServingEngine:
             # (measured durations included) via `events.simulate_module_events`'s
             # passive on_batch observer; the vectorized leg below reports
             # column-level tallies from `ModuleReplay.batches` instead
-            def hook(machine: Machine, start: float, end: float, rids) -> None:
+            def hook(machine: Machine, start: float, end: float, rids,
+                     closed: float) -> None:
+                reals = [float(ready_all[r]) for r in rids if not phantom[r]]
                 obs.batch_start(
                     m, machine.mid, start, end - start, len(rids),
-                    machine.config.batch,
-                    sum(1 for r in rids if phantom[r]),
+                    machine.config.batch, len(rids) - len(reals),
+                )
+                obs.waits(
+                    m, sum(closed - r for r in reals),
+                    len(reals) * (start - closed),
+                    len(reals) * (end - start), len(reals),
                 )
         if service_time is not None and service_time.kind != "analytic":
             # trace/live durations: the vectorized kernel assumes the
@@ -848,7 +860,8 @@ class ServingEngine:
             rep = ModuleReplay(finish, runs_to_assignment(runs, n_all), batches, phantom)
         elif ex is None:
             rep = replay_module(
-                machines, ready_all, runs, timeout=w, tail=tail, phantom=phantom
+                machines, ready_all, runs, timeout=w, tail=tail, phantom=phantom,
+                with_closed=obs is not None,
             )
             if obs is not None:
                 done_all = ~np.isnan(rep.finish)
@@ -866,6 +879,7 @@ class ServingEngine:
                         for mid, k in rep.batches.items()
                     ),
                 )
+                obs.waits(m, *rep.waits(ready_all, machines))
         else:
             def _measured(machine: Machine, _group: int) -> float:
                 t0 = time.perf_counter()

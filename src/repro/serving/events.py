@@ -189,7 +189,7 @@ def simulate_module_events(
     tail: str = "flush",
     executor: Callable[[Machine, int], float] | None = None,
     phantom: np.ndarray | None = None,
-    on_batch: "Callable[[Machine, float, float, list], None] | None" = None,
+    on_batch: "Callable[[Machine, float, float, list, float], None] | None" = None,
 ) -> tuple[np.ndarray, dict[int, int]]:
     """Simulate one module; returns ``(finish, batches_per_machine)``.
 
@@ -208,8 +208,9 @@ def simulate_module_events(
     discarded at end of stream instead of flushed.
 
     ``on_batch`` (when given) is a passive observer called at every batch
-    start with ``(machine, start, end, members)`` — the observability
-    layer's per-batch span feed; it never influences the simulation.
+    start with ``(machine, start, end, members, batch_ready)`` — the
+    observability layer's per-batch feed (``batch_ready`` is when the batch
+    closed); it never influences the simulation.
     """
     if tail not in ("flush", "drop"):
         raise ValueError(f"unknown tail policy {tail!r}")
@@ -236,6 +237,7 @@ def simulate_module_events(
             )
         else:
             drawn: list[float] = []
+            closed_at = core.queue[0][0] if core.queue else 0.0
 
             def dur(rids, _d=drawn) -> float:
                 d = (
@@ -253,7 +255,7 @@ def simulate_module_events(
         batches[mid] += 1
         finish[rids] = end
         if on_batch is not None:
-            on_batch(m, end - drawn[0], end, rids)
+            on_batch(m, end - drawn[0], end, rids, closed_at)
         heapq.heappush(heap, (end, _FREE, mid, 0))
 
     def close_batch(mid: int, batch_ready: float, now: float) -> None:

@@ -1,14 +1,17 @@
-"""Serving observability layer: tracing, metrics, and SLO-miss forensics.
+"""Serving observability layer: tracing, metrics, spans, and SLO-miss forensics.
 
-Three pieces, all passive (results are bit-identical with observability on,
+Four pieces, all passive (results are bit-identical with observability on,
 off, or sampled — the layer only *watches* the simulation):
 
 * :mod:`.trace`     — ring-buffered structured trace recorder with
   deterministic sampling; exports Chrome-trace/Perfetto JSON so a serve run
   renders as a per-machine/per-module timeline.
-* :mod:`.metrics`   — cheap per-module counters/gauges/histograms (batch
-  occupancy, dummy fill, backpressure stalls, queue depth, utilization),
-  flushed per control-plane epoch into ``ServeResult.metrics``.
+* :mod:`.metrics`   — cheap per-module counters and sums (batch occupancy,
+  dummy fill, backpressure stalls, utilization, member waits, host-span
+  seconds), flushed per control-plane epoch into ``ServeResult.metrics``.
+* :mod:`.spans`     — the served path's host spans on the profiler's clock
+  (``serve``, ``step``, ``dispatch``, ``sync``, ``gc``); all but ``serve``
+  and ``step`` also feed the metrics registry of the run in progress.
 * :mod:`.forensics` — classifies every missed/shed frame of a pipelined run
   into an exhaustive cause taxonomy with a conservation invariant; no
   opt-in needed (its columns are always on).
@@ -18,6 +21,27 @@ Enable via ``ServingEngine.run(..., observability=True)`` (or an
 :class:`Observability` runtime is the single object the serving loops talk
 to: every hook guards on the piece being enabled, and the loops guard on
 the runtime being present at all, so the disabled path stays hook-free.
+
+Fields of a module's metrics row beside the batch counts (all sums, so rows
+add up): ``collect_s`` — Σ over the real members of its started batches of
+(batch close − member ready); ``queue_s`` — Σ (batch start − batch close);
+``service_s`` — Σ (batch end − batch start); ``waited`` — those members.
+All four are in the loop's clock, on every path (event loop, segment fast
+path, flat engine).  With real executors (``launch/serve.py --real``) a row
+also carries ``dispatch_s`` / ``dispatch_n`` / ``dispatch_max_s`` (host
+time enqueueing the compiled forward; ``dispatch_n`` is the call count),
+and the same three for ``sync`` (the wait in ``block_until_ready``).  The
+``serve`` and ``step`` spans annotate the profiler trace only.  The
+``(host)`` row carries ``gc_s`` / ``gc_n`` / ``gc_max_s``: garbage
+collections while the run was in progress.
+
+The spans themselves land in a profiler trace taken around a real run, e.g.
+``jax.profiler.start_trace(dir)`` before and ``stop_trace()`` after
+``repro.launch.serve.main(["--arch", "smollm-360m", "--real",
+"--pipeline", "--trace"])``, and show on the trace's host plane next to the
+device's ops (TensorBoard's profile plugin or Perfetto read the
+``.xplane.pb``).  Each module's forward is jitted under its own name and
+``jax.named_scope``, so the device's XLA Modules line names it.
 """
 from __future__ import annotations
 
@@ -25,6 +49,7 @@ from dataclasses import dataclass
 
 from .forensics import MISS_CAUSES, MissReport, classify_misses
 from .metrics import MetricsRegistry, MetricsSnapshot
+from .spans import active, annotate, span
 from .trace import TraceRecorder
 
 
@@ -92,15 +117,23 @@ class Observability:
             )
 
     def batch_close(self, t: float, module: str, mid: int, size: int,
-                    cause: str, backlog: int) -> None:
+                    cause: str) -> None:
         """A formation buffer closed (``cause``: full/deadline/eos/drain)."""
         if self.metrics is not None:
-            self.metrics.close(module, cause, backlog)
+            self.metrics.close(module, cause)
         tr = self.trace
         if tr is not None and cause != "full":
             # partial flushes are the interesting (and rare) closes; full
             # closes are implied by the batch spans
             tr.instant(t, module, mid, f"flush:{cause}", size=size)
+
+    def waits(self, module: str, collect: float, queue: float,
+              service: float, waited: int) -> None:
+        """Waits of ``waited`` real members of started batches (sums; see
+        `MetricsRegistry.waits`): per batch on the event paths, per module
+        replay on the column paths."""
+        if self.metrics is not None:
+            self.metrics.waits(module, collect, queue, service, waited)
 
     def park(self, t: float, module: str) -> None:
         """A delivery parked under backpressure."""
@@ -124,7 +157,7 @@ class Observability:
         instants over a run equals terminal ``ServeResult.shed``.
         """
         if self.metrics is not None:
-            self.metrics.close("(ingress)", kind, 0)
+            self.metrics.close("(ingress)", kind)
         if self.trace is not None:
             self.trace.instant(t, None, 0, kind)
 
@@ -142,7 +175,7 @@ class Observability:
     def fail(self, t: float, module: str, mid: int) -> None:
         """A machine was declared dead (second missed heartbeat)."""
         if self.metrics is not None:
-            self.metrics.close(module, "machine_dead", 0)
+            self.metrics.close(module, "machine_dead")
         if self.trace is not None:
             self.trace.instant(t, module, mid, "fail")
 
@@ -226,5 +259,8 @@ __all__ = [
     "Observability",
     "ObservabilityConfig",
     "TraceRecorder",
+    "active",
+    "annotate",
     "classify_misses",
+    "span",
 ]

@@ -1,12 +1,16 @@
-"""Low-overhead metrics registry: per-module counters, gauges, histograms.
+"""Low-overhead metrics registry: per-module counters and sums.
 
 The registry accumulates cheap scalar state per module while the serving
 loop runs — integer counters (batches, close causes, backpressure parks),
 running sums for means (batch occupancy, dummy fill), a busy-time
-integrator for utilization, and small fixed-bucket histograms (queue depth
-at batch close).  At every control-plane epoch boundary (and once at end of
+integrator for utilization, the waits of each batch's real members
+(collection and queueing, in the loop's clock), and the seconds of the
+served path's host spans (`.spans`: executor dispatch and sync, garbage
+collections).  At every control-plane epoch boundary (and once at end of
 run) the accumulators flush into one row per module per epoch; the rows
-travel on ``ServeResult.metrics`` as a :class:`MetricsSnapshot`.
+travel on ``ServeResult.metrics`` as a :class:`MetricsSnapshot`.  Rows
+carry raw sums and counts, never only means, so a reader can sum them over
+rows.
 
 Everything here is plain Python arithmetic on a handful of attributes — no
 numpy allocation per event — so the registry stays inside the tracing
@@ -14,11 +18,7 @@ overhead budget (the ``pipeline_speed`` smoke gate's <= 10%).
 """
 from __future__ import annotations
 
-from bisect import bisect_right
 from dataclasses import dataclass, field
-
-# fixed queue-depth histogram buckets (instances waiting at batch close)
-_DEPTH_BUCKETS = (0, 1, 2, 4, 8, 16, 32, 64, 128, 256)
 
 
 class _ModuleAcc:
@@ -26,7 +26,7 @@ class _ModuleAcc:
 
     __slots__ = (
         "batches", "members", "phantoms", "slots", "parks", "busy",
-        "closes", "depth_hist", "depth_n",
+        "closes", "collect", "queue", "service", "waited", "spans",
     )
 
     def __init__(self):
@@ -40,12 +40,18 @@ class _ModuleAcc:
         self.parks = 0         # deliveries parked by backpressure
         self.busy = 0.0        # seconds of machine service time
         self.closes = {}       # close cause -> count
-        self.depth_hist = [0] * (len(_DEPTH_BUCKETS) + 1)
-        self.depth_n = 0
+        self.collect = 0.0     # sum over real members: batch close - member ready
+        self.queue = 0.0       # sum over real members: batch start - batch close
+        self.service = 0.0     # sum over real members: batch end - batch start
+        self.waited = 0        # real members behind the three sums
+        self.spans = {}        # span kind -> [seconds, count, longest]
 
     @property
     def empty(self) -> bool:
-        return self.batches == 0 and self.parks == 0 and not self.closes
+        return (
+            self.batches == 0 and self.parks == 0 and not self.closes
+            and not self.spans
+        )
 
 
 @dataclass
@@ -53,22 +59,30 @@ class MetricsSnapshot:
     """Flushed per-module-per-epoch metric rows (``ServeResult.metrics``)."""
 
     rows: list[dict] = field(default_factory=list)
-    depth_buckets: tuple = _DEPTH_BUCKETS
 
     def for_module(self, module: str) -> list[dict]:
         return [r for r in self.rows if r["module"] == module]
 
     def table(self) -> str:
-        """Aligned text table of the per-epoch rows (``serve.py --trace``)."""
+        """Aligned text table of the per-epoch rows (``serve.py --trace``).
+
+        ``collect_ms`` / ``queue_ms`` are the mean collection and queueing
+        wait per real member of the row's started batches."""
         cols = (
             "epoch", "module", "t0", "t1", "batches", "occupancy",
             "dummy_fill", "stalls", "utilization", "duration_err",
+            "collect_ms", "queue_ms",
         )
         lines = ["  ".join(f"{c:>12}" for c in cols)]
         for r in self.rows:
+            n = max(r.get("waited", 0), 1)
+            derived = {
+                "collect_ms": 1e3 * r.get("collect_s", 0.0) / n,
+                "queue_ms": 1e3 * r.get("queue_s", 0.0) / n,
+            }
             cells = []
             for c in cols:
-                v = r.get(c, 0.0)
+                v = derived[c] if c in derived else r.get(c, 0.0)
                 cells.append(
                     f"{v:>12.4f}" if isinstance(v, float) else f"{v:>12}"
                 )
@@ -103,17 +117,39 @@ class MetricsRegistry:
         acc.slots += cap
         acc.busy += dur
 
-    def close(self, module: str, cause: str, depth: int) -> None:
+    def close(self, module: str, cause: str) -> None:
         acc = self._mod(module)
         acc.closes[cause] = acc.closes.get(cause, 0) + 1
-        acc.depth_hist[bisect_right(_DEPTH_BUCKETS, depth)] += 1
-        acc.depth_n += 1
 
     def park(self, module: str) -> None:
         self._mod(module).parks += 1
 
-    def add_busy(self, module: str, seconds: float) -> None:
-        self._mod(module).busy += seconds
+    def waits(self, module: str, collect: float, queue: float,
+              service: float, waited: int) -> None:
+        """Fold the waits of ``waited`` real members of started batches:
+        Σ (batch close − member ready), Σ (batch start − batch close) and
+        Σ (batch end − batch start), all in the loop's clock.  One batch at
+        a time on the event paths, one module replay at a time on the
+        column paths."""
+        acc = self._mod(module)
+        acc.collect += float(collect)
+        acc.queue += float(queue)
+        acc.service += float(service)
+        acc.waited += waited
+
+    def span(self, module: str, kind: str, seconds: float, n: int = 1) -> None:
+        """``n`` host spans of ``kind`` (``dispatch``, ``sync``, ``gc``...)
+        that took ``seconds`` of wall time on behalf of ``module`` (``n=0``
+        puts the kind on the row at zero before any span of it ends)."""
+        acc = self._mod(module)
+        tot = acc.spans.get(kind)
+        if tot is None:
+            acc.spans[kind] = [seconds, n, seconds]
+        else:
+            tot[0] += seconds
+            tot[1] += n
+            if seconds > tot[2]:
+                tot[2] = seconds
 
     # -- column-level accumulation (segment fast path / flat engine) --------
     def bulk(self, module: str, *, batches: int, members: int,
@@ -154,8 +190,15 @@ class MetricsRegistry:
                 ),
                 "duration_err": duration_err,
                 "closes": dict(acc.closes),
-                "queue_depth_hist": list(acc.depth_hist),
+                "collect_s": acc.collect,
+                "queue_s": acc.queue,
+                "service_s": acc.service,
+                "waited": acc.waited,
             }
+            for kind, (total, n, longest) in acc.spans.items():
+                row[f"{kind}_s"] = total
+                row[f"{kind}_n"] = n
+                row[f"{kind}_max_s"] = longest
             self.rows.append(row)
             acc.reset()
         self._t0 = t1

@@ -39,7 +39,7 @@ class TraceRecorder:
     """Fixed-capacity ring buffer of typed serving events."""
 
     __slots__ = (
-        "capacity", "stride", "_buf", "_head", "dropped", "_n_hot", "recorded",
+        "capacity", "stride", "_buf", "_head", "dropped", "_n_hot",
     )
 
     def __init__(self, capacity: int = 200_000, sample: float = 1.0):
@@ -54,11 +54,9 @@ class TraceRecorder:
         self._head = 0
         self.dropped = 0       # events overwritten by the ring
         self._n_hot = 0        # hot-event counter driving the sample stride
-        self.recorded = 0      # events actually stored (pre-ring)
 
     # -- recording ----------------------------------------------------------
     def _push(self, ev: tuple) -> None:
-        self.recorded += 1
         buf = self._buf
         if len(buf) < self.capacity:
             buf.append(ev)

@@ -100,8 +100,10 @@ def run_flat_segment(
     indistinguishable from the general path's.
 
     ``obs`` (an `observability.Observability`) receives *column-level*
-    metrics only — per-module batch counts, occupancy, and exact busy time
-    from the per-machine batch tallies — never per-event trace spans:
+    metrics only — per-module batch counts, occupancy, exact busy time
+    from the per-machine batch tallies, and the members' collection /
+    queueing / service sums from the replay's per-request columns — never
+    per-event trace spans:
     keeping the fast path allocation-free per event is what holds sampled
     tracing inside the CI overhead gate.
     """
@@ -169,7 +171,10 @@ def run_flat_segment(
         machines = st.machines
         timeout = {mm.mid: st.cores[mm.mid].timeout for mm in machines}
         runs = dispatch_runs(machines, instances.size, st.policy)
-        rep = replay_module(machines, ready_inst, runs, timeout=timeout, tail=tail)
+        rep = replay_module(
+            machines, ready_inst, runs, timeout=timeout, tail=tail,
+            with_closed=obs is not None,
+        )
         done = rep.done
         # per-frame finish = max over the frame's completed instances
         # (partial completion proceeds with the instances that did finish)
@@ -219,6 +224,7 @@ def run_flat_segment(
                     k * by_mid[mid].duration for mid, k in rep.batches.items()
                 ),
             )
+            obs.waits(m, *rep.waits(ready_inst, machines))
 
     sink_finish = np.stack([ft.finish[s] for s in sinks])
     ok = ~np.isnan(sink_finish).any(axis=0)

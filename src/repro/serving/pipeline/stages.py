@@ -519,9 +519,7 @@ class ModuleStage:
                     if i.frame >= 0:
                         col[i.frame] = True
         if self.obs is not None:
-            self.obs.batch_close(
-                now, self.name, mid, len(core.buf), cause, self.backlog
-            )
+            self.obs.batch_close(now, self.name, mid, len(core.buf), cause)
         core.close(batch_ready)
         if self.watchdog is not None:
             # detection heartbeat: the batch must complete within k x its
@@ -534,6 +532,9 @@ class ModuleStage:
     def start_next(self, mid: int, now: float, push: Callable) -> bool:
         """Start the next queued batch on ``mid`` (unless backpressured)."""
         core = self.cores[mid]
+        tel = self.obs
+        # the close time of the batch about to start, for the waits' split
+        closed_at = core.queue[0][0] if tel is not None and core.queue else 0.0
         src, obs = self.service_time, self.service_obs
         if src is None and obs is None:
             started = core.start(now, lambda members: core.machine.config.duration)
@@ -558,17 +559,21 @@ class ModuleStage:
         self.stats.batches += 1
         self.backlog -= len(members)
         self.in_service[mid] = members
-        tel = self.obs
         if tel is not None:
             d = (
                 drawn[0]
                 if (src is not None or obs is not None) and drawn
                 else core.machine.config.duration
             )
+            start = end - d
+            reals = [i.ready for i in members if i.frame >= 0]
             tel.batch_start(
-                self.name, mid, end - d, d, len(members),
-                core.machine.config.batch,
-                sum(1 for i in members if i.frame < 0),
+                self.name, mid, start, d, len(members),
+                core.machine.config.batch, len(members) - len(reals),
+            )
+            tel.waits(
+                self.name, sum(closed_at - r for r in reals),
+                len(reals) * (start - closed_at), len(reals) * d, len(reals),
             )
             tel.queue_depth(now, self.name, self.backlog)
         push(end, _K_FREE, self.name, (mid,))

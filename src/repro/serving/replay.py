@@ -59,6 +59,8 @@ class ModuleReplay:
     assignment: np.ndarray  # serving machine id per request
     batches: dict[int, int]  # executed batches per machine
     phantom: np.ndarray | None = None  # frontend dummy-request mask (None = none)
+    # per-request close time of its batch (NaN = dropped); only when asked
+    closed: np.ndarray | None = None
 
     @property
     def done(self) -> np.ndarray:
@@ -74,6 +76,26 @@ class ModuleReplay:
     @property
     def n_batches(self) -> int:
         return sum(self.batches.values())
+
+    def waits(
+        self, ready: np.ndarray, machines: Sequence[Machine]
+    ) -> tuple[float, float, float, int]:
+        """``(collect, queue, service, n)`` summed over the ``n`` completed
+        real requests (needs ``closed``): Σ (batch close − ready), Σ (batch
+        start − batch close) and Σ (finish − batch start), the column form of
+        the event paths' per-batch feed (`Observability.waits`)."""
+        ok = self.done & self.real
+        lut = np.zeros(max(m.mid for m in machines) + 1)
+        for m in machines:
+            lut[m.mid] = m.config.duration
+        dur = lut[self.assignment[ok]]
+        closed = self.closed[ok]
+        return (
+            float(np.sum(closed - ready[ok])),
+            float(np.sum(self.finish[ok] - dur - closed)),
+            float(np.sum(dur)),
+            int(ok.sum()),
+        )
 
 
 def runs_to_assignment(runs: Sequence[tuple[int, int]], n: int) -> np.ndarray:
@@ -339,6 +361,7 @@ def replay_machine(
     timeout: float | None = None,
     tail: str = "flush",
     phantom: np.ndarray | None = None,
+    closed: np.ndarray | None = None,
 ) -> tuple[np.ndarray, int]:
     """Replay one machine; returns ``(finish, n_batches)``.
 
@@ -347,6 +370,8 @@ def replay_machine(
     module docstring).  ``finish[i]`` is the absolute completion time
     of request ``i`` (NaN when the tail is dropped).  ``phantom`` marks
     frontend dummy requests (see `_batch_bounds` for their semantics).
+    ``closed`` (an output array of ``ready``'s length, when given) receives
+    each covered request's batch close time.
     """
     if tail not in ("flush", "drop"):
         raise ValueError(f"unknown tail policy {tail!r}")
@@ -378,6 +403,8 @@ def replay_machine(
     end = np.asarray(end_l)
     covered = int(sizes.sum())
     finish[:covered] = np.repeat(end, sizes)
+    if closed is not None:
+        closed[:covered] = np.repeat(g_ready, sizes)
     return finish, ng
 
 
@@ -390,6 +417,7 @@ def replay_module(
     tail: str = "flush",
     method: str = "vectorized",
     phantom: np.ndarray | None = None,
+    with_closed: bool = False,
 ) -> ModuleReplay:
     """Replay one module's machines over a sorted request-ready stream.
 
@@ -401,7 +429,9 @@ def replay_module(
     cross-validation and whenever real executors are involved).  ``phantom``
     marks frontend dummy requests: they fill batch slots but never arm flush
     deadlines or force end-of-stream flushes, and callers exclude them from
-    latency statistics via ``ModuleReplay.real``.
+    latency statistics via ``ModuleReplay.real``.  ``with_closed`` (vectorized
+    only) also fills ``ModuleReplay.closed``, each request's batch close
+    time, for the observability layer's wait split.
     """
     ready = np.asarray(ready, dtype=np.float64)
     n = ready.size
@@ -418,6 +448,7 @@ def replay_module(
     if method != "vectorized":
         raise ValueError(f"unknown method {method!r}")
     finish = np.full(n, np.nan)
+    closed = np.full(n, np.nan) if with_closed else None
     batches: dict[int, int] = {}
     # one stable argsort groups requests by machine while preserving arrival
     # order within each group (much cheaper than a per-machine == scan)
@@ -431,13 +462,16 @@ def replay_module(
             continue
         idx = order[lo:hi]
         w = timeout.get(m.mid) if isinstance(timeout, Mapping) else timeout
+        c = np.full(idx.size, np.nan) if with_closed else None
         f, nb = replay_machine(
             ready[idx], m.config.batch, m.config.duration, timeout=w, tail=tail,
-            phantom=None if phantom is None else phantom[idx],
+            phantom=None if phantom is None else phantom[idx], closed=c,
         )
         finish[idx] = f
+        if with_closed:
+            closed[idx] = c
         batches[m.mid] = nb
-    return ModuleReplay(finish, assignment, batches, phantom)
+    return ModuleReplay(finish, assignment, batches, phantom, closed)
 
 
 def fanout_counts(n: int, fanout: float) -> np.ndarray:

@@ -39,6 +39,7 @@ from typing import Callable, Mapping, Sequence
 import numpy as np
 
 from ..core.dispatch import Machine
+from .observability.spans import annotate
 
 
 def _key_stream(seed: int, module: str, batch: int) -> np.random.Generator:
@@ -234,7 +235,9 @@ class LiveServiceTime(ServiceTimeSource):
     run that silently mixed measured and modeled times would report the
     model as a measurement.  ``cache=False``
     re-measures every batch (honest but slow — every simulated batch is a
-    real forward).
+    real forward).  Each executor call is a ``step <module> b<batch>``
+    profiler step (`observability.spans.annotate`), numbered by the calls
+    made; it feeds no counter.
     """
 
     kind = "live"
@@ -249,6 +252,14 @@ class LiveServiceTime(ServiceTimeSource):
         if warmup < 0:
             raise ValueError("warmup must be >= 0")
         self.executors = dict(executors)
+        for module, ex in self.executors.items():
+            # an executor that names itself spans its calls under that name
+            name = getattr(ex, "name", module)
+            if name != module:
+                raise ValueError(
+                    f"live service time: the executor of module {module!r} "
+                    f"is named {name!r}; its spans would land on another row"
+                )
         self.warmup = int(warmup)
         self.cache = bool(cache)
         self.reset()
@@ -256,6 +267,7 @@ class LiveServiceTime(ServiceTimeSource):
     def reset(self) -> None:
         self.measured: dict[tuple[str, int], list[float]] = {}
         self._cached: dict[tuple[str, int], float] = {}
+        self._steps = 0  # executor calls made: each one's profiler step number
 
     def duration(self, module: str, machine: Machine, n_members: int) -> float:
         b = machine.config.batch
@@ -269,9 +281,11 @@ class LiveServiceTime(ServiceTimeSource):
                 f"live service time: no executor for module {module!r} "
                 f"(executors: {sorted(self.executors)})"
             )
-        t0 = time.perf_counter()
-        ex(b)
-        d = time.perf_counter() - t0
+        self._steps += 1
+        with annotate("step", module, b, step=self._steps):
+            t0 = time.perf_counter()
+            ex(b)
+            d = time.perf_counter() - t0
         obs = self.measured.setdefault(key, [])
         obs.append(d)
         if self.cache and len(obs) > self.warmup:

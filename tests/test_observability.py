@@ -12,6 +12,7 @@ bit-identical under burst deadlines (the PR-6 inertness finding the
 rename records); and the BENCH_serving.json writer merges by name into a
 deterministic, schema-versioned document.
 """
+import gc
 import json
 import os
 import sys
@@ -32,6 +33,7 @@ from repro.serving import (
     TraceRecorder,
 )
 from repro.serving.arrivals import trace_arrivals
+from repro.serving.pipeline import PipelineConfig
 from repro.workloads import synth_profiles
 from repro.workloads.apps import app_by_name, make_workload
 
@@ -276,7 +278,11 @@ class TestMetrics:
             ),
             offered_rate=1.3 * 150.0, pipeline=True, observability=True,
         )
-        rows = [r for r in res.metrics.rows if r["module"] != "(ingress)"]
+        # module rows: not the ingress sheds, nor the host's gc spans
+        rows = [
+            r for r in res.metrics.rows
+            if r["module"] not in ("(ingress)", "(host)")
+        ]
         assert rows
         for r in rows:
             assert 0.0 < r["occupancy"] <= 1.0
@@ -290,6 +296,136 @@ class TestMetrics:
         table = res.metrics.table()
         assert "occupancy" in table and "utilization" in table
         assert res.metrics.for_module(rows[0]["module"])
+
+
+# ------------------------------------- member waits and the host spans
+
+
+def _waits(res, module):
+    """The run's summed wait counters of ``module`` over its rows."""
+    rows = res.metrics.for_module(module)
+    return {
+        k: sum(r[k] for r in rows)
+        for k in ("collect_s", "queue_s", "service_s", "waited")
+    }
+
+
+WAIT_PATHS = {
+    "reference": dict(pipeline=PipelineConfig(reference=True)),
+    "fast_path": dict(pipeline=True),
+    "flat": dict(pipeline=False),
+}
+
+
+class TestWaits:
+    """collect + queue + service of a module's real members is each member's
+    finish − ready, summed, on every path that counts batches."""
+
+    @pytest.mark.parametrize("timeout", [None, "budget"])
+    @pytest.mark.parametrize("arrivals", ["poisson", "mmpp"])
+    def test_waits_add_up_and_paths_agree(self, timeout, arrivals):
+        plan = suite_plan("face", 150.0, 2.5)
+        eng = ServingEngine(plan)
+        runs = {
+            name: eng.run(
+                600, 150.0, arrivals=arrivals, seed=1, timeout=timeout,
+                observability=True, **kw,
+            )
+            for name, kw in WAIT_PATHS.items()
+        }
+        for name, res in runs.items():
+            for m, st in res.module_stats.items():
+                w = _waits(res, m)
+                assert w["waited"] == len(st.latencies) > 0, name
+                assert w["collect_s"] >= 0.0 and w["queue_s"] >= -1e-9
+                whole = w["collect_s"] + w["queue_s"] + w["service_s"]
+                assert whole == pytest.approx(sum(st.latencies), rel=1e-9), (
+                    name, m,
+                )
+            pr = res.pipeline
+            if pr is not None:
+                # fanout 1 at the source: a member is its frame, so the
+                # frame table's finish − avail is the same sum
+                src = "face_detect"
+                ok = ~np.isnan(pr.finish[src])
+                w = _waits(res, src)
+                assert w["collect_s"] + w["queue_s"] + w["service_s"] == (
+                    pytest.approx(
+                        float(np.sum(pr.finish[src][ok] - pr.avail[src][ok])),
+                        rel=1e-9,
+                    )
+                )
+        ref = runs["reference"]
+        for name in ("fast_path", "flat"):
+            for m in ref.module_stats:
+                a, b = _waits(ref, m), _waits(runs[name], m)
+                assert a["waited"] == b["waited"], (name, m)
+                for k in ("collect_s", "queue_s", "service_s"):
+                    assert b[k] == pytest.approx(a[k], rel=1e-9, abs=1e-12), (
+                        name, m, k,
+                    )
+
+    def test_deadline_waits_show_in_the_table(self):
+        plan = suite_plan("face", 150.0, 2.5)
+        res = ServingEngine(plan).run(
+            400, 150.0, arrivals="poisson", seed=0, timeout="budget",
+            pipeline=True, observability=True,
+        )
+        table = res.metrics.table()
+        assert "collect_ms" in table and "queue_ms" in table
+        assert any(
+            r["collect_s"] > 0.0 for r in res.metrics.rows if r["waited"]
+        )
+
+
+class TestHostSpans:
+    @pytest.mark.parametrize("pipeline", [True, False])
+    def test_gc_hook_is_passive_and_removed(self, pipeline):
+        plan = suite_plan("face", 150.0, 2.5)
+        eng = ServingEngine(plan)
+        kw = dict(arrivals="mmpp", seed=0, timeout="budget",
+                  pipeline=pipeline)
+        hooks = list(gc.callbacks)
+        threshold = gc.get_threshold()
+        gc.set_threshold(50)  # many collections inside the run
+        try:
+            off = eng.run(800, 150.0, **kw)
+            on = eng.run(800, 150.0, observability=True, **kw)
+        finally:
+            gc.set_threshold(*threshold)
+        assert result_key(off) == result_key(on)
+        assert gc.callbacks == hooks
+        (host,) = on.metrics.for_module("(host)")
+        assert host["gc_n"] > 0
+        assert 0.0 < host["gc_max_s"] <= host["gc_s"]
+
+    def test_a_run_without_collections_reads_gc_zero(self):
+        plan = suite_plan("face", 150.0, 2.5)
+        gc.disable()
+        try:
+            res = ServingEngine(plan).run(200, 150.0, observability=True)
+        finally:
+            gc.enable()
+        (host,) = res.metrics.for_module("(host)")
+        assert (host["gc_s"], host["gc_n"]) == (0.0, 0)
+
+    def test_spans_feed_only_the_active_runtime(self):
+        from repro.serving.observability import Observability, active, span
+
+        obs = Observability(ObservabilityConfig(trace=False))
+        with span("dispatch", "m", 4):
+            pass
+        with active(obs):
+            with span("dispatch", "m", 4), span("sync", "m", 4):
+                pass
+            with span("dispatch", "m", 1):
+                pass
+        with span("sync", "m", 1):
+            pass
+        obs.metrics.flush(1.0, {"m": 1})
+        (row,) = obs.metrics.snapshot().for_module("m")
+        assert (row["dispatch_n"], row["sync_n"]) == (2, 1)
+        assert 0.0 <= row["dispatch_max_s"] <= row["dispatch_s"]
 
 
 # ----------------------------- relax: scoped inertness (PR-6, promoted PR-8)

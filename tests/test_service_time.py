@@ -222,3 +222,79 @@ class TestCorrectionConvergence:
         errs = [e.duration_err for e in res.epochs if e.duration_err > 0.0]
         assert errs and all(e == pytest.approx(0.3, abs=0.06) for e in errs)
         assert not any(e.corrections for e in res.epochs)
+
+
+class TestSpans:
+    """The served path's host spans (`serving.observability.spans`)."""
+
+    def test_executor_spans_feed_only_the_active_runtime(self):
+        from repro.serving.observability import (
+            Observability,
+            ObservabilityConfig,
+            active,
+            span,
+        )
+
+        def ex(b):
+            with span("dispatch", "m", b):
+                pass
+
+        obs = Observability(ObservabilityConfig(trace=False))
+        src = LiveServiceTime({"m": ex}, cache=False)
+        m = _machine(batch=8)
+        src.duration("m", m, 8)  # no runtime active: nothing is counted
+        with active(obs):
+            for _ in range(3):
+                src.duration("m", m, 8)
+        obs.metrics.flush(1.0, {"m": 1})
+        (row,) = obs.metrics.snapshot().for_module("m")
+        assert row["dispatch_n"] == 3
+        # the span lies inside the timed call
+        assert row["dispatch_s"] <= sum(src.measured["m", 8][1:])
+        # the step around each call annotates the profiler only
+        assert not any(k.startswith("step_") for k in row)
+
+    def test_executor_named_for_another_module_is_refused(self):
+        class Ex:
+            name = "other"
+
+            def __call__(self, b):
+                pass
+
+        with pytest.raises(ValueError, match="named 'other'"):
+            LiveServiceTime({"m": Ex()})
+        LiveServiceTime({"other": Ex()})
+
+    def test_executor_spans_land_on_the_profilers_host_plane(self, tmp_path):
+        import jax
+        from jax.profiler import ProfileData
+
+        from repro.configs import get_config
+        from repro.launch.serve import ModuleExecutor
+
+        ex = ModuleExecutor(get_config("smollm-360m", smoke=True), seq=8)
+        ex(2)  # compiles outside the trace
+        hlo = ex.compiled[2].as_text()
+        # the forward is jitted under the module's name, its body scoped
+        assert "jit_forward_smollm_360m" in hlo
+        assert "/smollm-360m/" in hlo
+        live = LiveServiceTime({"smollm-360m": ex}, cache=False)
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            live.duration("smollm-360m", _machine("smollm-360m", batch=2), 2)
+        finally:
+            jax.profiler.stop_trace()
+        (path,) = tmp_path.glob("plugins/profile/*/*.xplane.pb")
+        names = {
+            e.name
+            for plane in ProfileData.from_file(str(path)).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines
+            for e in line.events
+        }
+        assert {
+            "step smollm-360m b2",
+            "dispatch smollm-360m b2",
+            "sync smollm-360m b2",
+        } <= names
+        assert not any(n.startswith("executor ") for n in names)
