@@ -32,6 +32,12 @@ TOL = {jnp.float32: 2e-5, jnp.bfloat16: 2e-2}
         (2, 256, 4, 1, 128, None),  # MQA
         (1, 384, 6, 2, 128, 128),  # sliding window
         (2, 128, 8, 8, 256, None),  # MHA, gemma head_dim
+        # the served widths, and whole-axis blocks:
+        (3, 128, 15, 5, 64, None),  # smollm-360m GQA 15/5
+        (2, 128, 20, 20, 128, None),  # qwen1.5-4b MHA 20/20
+        (2, 64, 4, 2, 64, None),  # a whole-axis block under 128
+        (2, 120, 6, 2, 64, None),  # ... not a multiple of 8 either
+        (2, 128, 4, 2, 64, 32),  # window shorter than the sequence
     ],
 )
 def test_flash_attention_sweep(B, S, Hq, Hkv, D, window, dtype):
@@ -178,6 +184,39 @@ def test_rmsnorm_takes_kernel_at_unaligned_width(monkeypatch):
     out = ops.rmsnorm(x, w)
     assert dict(ops.TAKEN) == {("rmsnorm", "interpret"): 1}
     assert rel_err(out, ref.rmsnorm(x, w)) < TOL[jnp.bfloat16]
+
+
+def test_attention_sends_one_block_sequences_to_xla(monkeypatch):
+    """Where the kernels run, a sequence that one 128 block holds takes the
+    XLA attention and a longer one the kernel, each counted in TAKEN."""
+    monkeypatch.setattr(ops, "_mode", lambda: "interpret")
+    monkeypatch.setattr(ops, "TAKEN", type(ops.TAKEN)())
+    for S in (128, 256):
+        ks = jax.random.split(jax.random.key(S), 3)
+        q = jax.random.normal(ks[0], (1, S, 2, 64))
+        k, v = (jax.random.normal(kk, (1, S, 1, 64)) for kk in ks[1:])
+        assert rel_err(ops.attention(q, k, v), ref.attention(q, k, v)) < TOL[jnp.float32]
+    assert dict(ops.TAKEN) == {("attention", "xla"): 1, ("attention", "interpret"): 1}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize(
+    "B,S,Hq,Hkv,D,window",
+    [
+        (3, 128, 15, 5, 64, None),  # smollm-360m GQA 15/5
+        (2, 128, 20, 20, 128, None),  # qwen1.5-4b MHA 20/20
+        (2, 120, 6, 2, 64, None),  # a sequence under one block
+        (2, 128, 4, 2, 64, 32),  # window shorter than the sequence
+    ],
+)
+def test_xla_attention_sweep(B, S, Hq, Hkv, D, window, dtype):
+    ks = jax.random.split(jax.random.key(1), 3)
+    q = jax.random.normal(ks[0], (B, S, Hq, D), dtype)
+    k = jax.random.normal(ks[1], (B, S, Hkv, D), dtype)
+    v = jax.random.normal(ks[2], (B, S, Hkv, D), dtype)
+    out = ops._xla_attention(q, k, v, causal=True, window=window, scale=None)
+    assert out.dtype == dtype
+    assert rel_err(out, ref.attention(q, k, v, causal=True, window=window)) < TOL[dtype]
 
 
 def test_flash_decode_multi_kv_heads_untiled_length():
