@@ -8,6 +8,7 @@ from . import (
     gemma3_1b,
     gemma_7b,
     jamba_v01_52b,
+    moonlight_16b_a3b,
     musicgen_medium,
     qwen15_4b,
     qwen2_moe_a27b,
@@ -27,6 +28,7 @@ _MODULES = {
     "xlstm-125m": xlstm_125m,
     "qwen2-moe-a2.7b": qwen2_moe_a27b,
     "qwen1.5-4b": qwen15_4b,
+    "moonlight-16b-a3b": moonlight_16b_a3b,
 }
 
 ARCHS: dict[str, ArchConfig] = {k: m.CONFIG for k, m in _MODULES.items()}

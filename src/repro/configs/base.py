@@ -55,6 +55,12 @@ class ArchConfig:
     n_dense_layers: int = 0
     moe_capacity_factor: float = 1.25
     router_aux_coef: float = 0.01
+    # routing: 'softmax' scores, or 'sigmoid_noaux' (deepseek-v3's noaux_tc):
+    # sigmoid scores, the top-k chosen by score plus a per-expert
+    # score-correction bias that weighs nothing; either way the chosen
+    # scores are divided by their sum, then times routed_scale
+    router_score: str = "softmax"
+    routed_scale: float = 1.0
     # --- hybrid / ssm ---
     hybrid_pattern: tuple[str, ...] | None = None  # mixer per layer, cycled
     d_state: int = 16

@@ -252,7 +252,8 @@ def attention(q, k, v, *, causal=True, window=None, scale=None, q_offset=0, kv_l
 
 def mla_prefill_attention(q_nope, q_rope, k_nope, kr, v, *, scale):
     """MLA naive-form prefill attention with the head-concat INSIDE the
-    shard_map boundary: q = [q_nope ; q_rope], k = [k_nope ; broadcast(kr)].
+    shard_map boundary: q = [q_nope ; q_rope], k = [k_nope ; broadcast(kr)],
+    then `attention`'s dispatch (the per-device body sees no mesh).
 
     Keeping the concatenation of the per-head (sharded) and shared-rope
     (replicated) halves inside per-device code stops GSPMD from gathering
@@ -266,9 +267,8 @@ def mla_prefill_attention(q_nope, q_rope, k_nope, kr, v, *, scale):
             [kn, jnp.broadcast_to(krr[:, :, None], (*kn.shape[:3], dr))], -1
         )
         q = jnp.concatenate([qn, qr], -1)
-        if k.shape[1] >= 8192 and k.shape[1] % 1024 == 0:
-            return ref.attention_chunked(q, k, vv, causal=True, scale=scale)
-        return ref.attention(q, k, vv, causal=True, scale=scale)
+        with mesh_context(None):
+            return attention(q, k, vv, causal=True, scale=scale)
 
     ctx = _MESH_CTX.get()
     if ctx is not None and B % ctx.dp_size == 0 and H % ctx.model_size == 0:
@@ -285,6 +285,31 @@ def mla_prefill_attention(q_nope, q_rope, k_nope, kr, v, *, scale):
         )
         return fn(q_nope, q_rope, k_nope, kr, v)
     return body(q_nope, q_rope, k_nope, kr, v)
+
+
+def expert_gmm(x, w, group_sizes, *, layer=None):
+    """The experts' grouped matmul: rows of ``x`` (m, k) sorted by expert,
+    ``group_sizes[e]`` of them for expert ``e``, times ``w[e]`` (k, n).
+
+    With ``layer``, ``w`` is a stack (L, E, k, n) of every layer's experts and
+    the product is with ``w[layer]``: the kernel reads that layer's experts
+    in place, as groups ``layer * E + e`` of the stack seen as (L * E, k, n)
+    with every other group empty."""
+    mode = _take("expert_gmm", True)
+    w = w.astype(x.dtype)  # no-op where the weights are stored in the compute dtype
+    if mode != "ref":
+        from .expert_gmm import gmm
+
+        if layer is not None:
+            L, E = w.shape[:2]
+            w = w.reshape(L * E, *w.shape[2:])
+            group_sizes = jax.lax.dynamic_update_slice(
+                jnp.zeros((L * E,), group_sizes.dtype), group_sizes, (layer * E,)
+            )
+        return gmm(x, w, group_sizes, interpret=mode == "interpret")
+    if layer is not None:
+        w = w[layer]
+    return jax.lax.ragged_dot(x, w, group_sizes)
 
 
 def decode_attention(q, k_cache, v_cache, lengths, *, window=None, scale=None):

@@ -58,11 +58,16 @@ from .compile_cache import enable_compile_cache
 def _named_forward(model: Model, name: str):
     """The module's forward as a function named for it, its body under
     ``jax.named_scope(name)``: the compiled executable (the device trace's
-    XLA Modules line) and its ops' metadata then say which module ran."""
+    XLA Modules line) and its ops' metadata then say which module ran.
+
+    It returns the logits, and for an arch with MoE layers the pair
+    ``(logits, routes)``: the expert ids each MoE layer chose for each token,
+    ``(n_moe_layers, batch * seq, top_k)`` int32."""
 
     def forward(params, tokens):
         with jax.named_scope(name):
-            return model.forward(params, tokens).logits
+            out = model.forward(params, tokens, hold_experts=True)
+        return out.logits if out.routes is None else (out.logits, out.routes)
 
     forward.__name__ = forward.__qualname__ = (
         "forward_" + re.sub(r"\W", "_", name)
@@ -74,12 +79,13 @@ class ModuleExecutor:
     """One module's jitted forward over seeded random token ids.
 
     ``ex(b)`` runs a ``(b, seq)`` batch on the default device and blocks
-    until its logits are ready (the `ServingEngine` executor contract), and
-    returns them.  The first call at a batch size compiles it ahead of the
-    run and records the compile seconds in ``compile_s`` (set-up, never a
-    step).  Each later call is two spans (`serving.observability.spans`):
+    until its output is ready (the `ServingEngine` executor contract), and
+    returns it: the logits, or ``(logits, routes)`` for an MoE arch.  The
+    first call at a batch size compiles it ahead of the run and records the
+    compile seconds in ``compile_s`` (set-up, never a step).  Each later
+    call is two spans (`serving.observability.spans`):
     ``dispatch <name> b<b>`` while the host enqueues the compiled forward,
-    and ``sync <name> b<b>`` while it waits for the logits.  ``name`` is
+    and ``sync <name> b<b>`` while it waits for the output.  ``name`` is
     ``cfg.name``: a module served by this executor must carry the arch's
     name, since its spans land on that module's registry row
     (`LiveServiceTime` refuses an executor bound to another name).
@@ -97,7 +103,7 @@ class ModuleExecutor:
         self.compile_s: dict[int, float] = {}
         self._tokens: dict[int, jax.Array] = {}
 
-    def __call__(self, b: int) -> jax.Array:
+    def __call__(self, b: int):
         if b not in self.compiled:
             toks = jax.random.randint(
                 jax.random.fold_in(self._tok_key, b),
@@ -110,7 +116,7 @@ class ModuleExecutor:
         with span("dispatch", self.name, b):
             out = self.compiled[b](self.params, self._tokens[b])
         with span("sync", self.name, b):
-            return out.block_until_ready()
+            return jax.block_until_ready(out)
 
 
 def build_executors(

@@ -232,7 +232,13 @@ def mla_forward(
 ) -> tuple[jax.Array, Params | None]:
     """Multi-head Latent Attention.  Prefill runs the naive (expanded) form;
     decode runs the absorbed form against the compressed cache — a single
-    MQA-style flash-decode with K = [c_kv ; k_rope], V = c_kv."""
+    MQA-style flash-decode with K = [c_kv ; k_rope], V = c_kv.  Its ops carry
+    the ``mla`` name scope."""
+    with jax.named_scope("mla"):
+        return _mla(p, cfg, x, positions, cache, idx)
+
+
+def _mla(p, cfg, x, positions, cache, idx):
     B, S, _ = x.shape
     H, dn, dv = cfg.n_heads, cfg.hdim, cfg.vdim
     dc, dr = cfg.kv_lora_rank, cfg.rope_head_dim

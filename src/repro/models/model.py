@@ -121,14 +121,15 @@ def _block_apply(
 
     x = constrain_activations(x + y)
     aux = jnp.zeros((), jnp.float32)
+    routes = None
     if spec.ffn != "none":
         h = Lyr.apply_norm(cfg, p["ffn_norm"], x)
         if spec.ffn == "moe":
-            y, aux = Moe.moe_forward(p["ffn"], cfg, h, mesh_info=mesh_info)
+            y, aux, routes = Moe.moe_forward(p["ffn"], cfg, h, mesh_info=mesh_info)
         else:
             y = Lyr.mlp_forward(p["ffn"], h, cfg.act)
         x = constrain_activations(x + y)
-    return x, new_cache, aux
+    return x, new_cache, aux, routes
 
 
 @dataclass
@@ -137,6 +138,9 @@ class ModelOutput:
     cache: Any
     aux_loss: jax.Array
     hidden: jax.Array | None = None
+    # expert ids (n_moe_layers, B*S, top_k) int32, in layer order; None
+    # for an arch without MoE layers
+    routes: jax.Array | None = None
 
 
 class Model:
@@ -216,7 +220,11 @@ class Model:
         idx=None,
         return_hidden: bool = False,
         compute_logits: bool = True,
+        hold_experts: bool = False,
     ) -> ModelOutput:
+        """``hold_experts`` reads each scanned expert layer's weights in place
+        (`_hold_experts`); for a forward that no gradient is taken through,
+        since each layer's gradient would then be the whole stack's shape."""
         cfg = self.cfg
         if embeds is None:
             x = Lyr.embed(params["embed"], cfg, tokens, self.cdtype)
@@ -231,63 +239,84 @@ class Model:
 
         aux_total = jnp.zeros((), jnp.float32)
         new_caches = [] if cache is not None else None
+        routes = []  # (layers, B*S, top_k) blocks of the MoE layers, in order
         for si, (pattern, repeats) in enumerate(self.segments):
             seg_params = params["segments"][si]
             seg_cache = cache[si] if cache is not None else None
 
             def apply_pattern(x, blk_params, blk_cache):
-                new_bc = []
+                new_bc, rs = [], []
                 aux = jnp.zeros((), jnp.float32)
                 for j, spec in enumerate(pattern):
                     c_j = blk_cache[j] if blk_cache is not None else None
-                    x, nc, a = _block_apply(
+                    x, nc, a, r = _block_apply(
                         blk_params[j], cfg, spec, x, positions, c_j, idx, self.mesh_info
                     )
                     new_bc.append(nc)
                     aux = aux + a
-                return x, tuple(new_bc), aux
+                    if r is not None:
+                        rs.append(r)
+                return x, tuple(new_bc), aux, tuple(rs)
 
             if cfg.remat:
                 apply_pattern = jax.checkpoint(apply_pattern)
 
             if repeats == 1:
-                x, nc, aux = apply_pattern(x, seg_params, seg_cache)
+                x, nc, aux, rs = apply_pattern(x, seg_params, seg_cache)
                 aux_total = aux_total + aux
+                routes += [r[None] for r in rs]
                 if new_caches is not None:
                     new_caches.append(nc)
             else:
+                seg_params, held = (
+                    self._hold_experts(pattern, seg_params) if hold_experts else (seg_params, {})
+                )
 
                 def scan_body(carry, xs):
                     x, aux_acc = carry
-                    blk_params, blk_cache = xs
-                    x, nc, aux = apply_pattern(x, blk_params, blk_cache)
-                    return (x, aux_acc + aux), nc
-
-                if seg_cache is None:
-
-                    def scan_body_nc(carry, blk_params):
-                        x, aux_acc = carry
-                        x, _nc, aux = apply_pattern(x, blk_params, None)
-                        return (x, aux_acc + aux), None
-
-                    (x, aux_total), _ = jax.lax.scan(
-                        scan_body_nc, (x, aux_total), seg_params
+                    i, blk_params, blk_cache = xs
+                    blk_params = tuple(
+                        {**b, "ffn": {**b["ffn"], **held[j], "layer": i}} if j in held else b
+                        for j, b in enumerate(blk_params)
                     )
-                    if new_caches is not None:
-                        new_caches.append(None)
-                else:
-                    (x, aux_total), nc = jax.lax.scan(
-                        scan_body, (x, aux_total), (seg_params, seg_cache)
-                    )
-                    if new_caches is not None:
-                        new_caches.append(nc)
+                    x, nc, aux, rs = apply_pattern(x, blk_params, blk_cache)
+                    return (x, aux_acc + aux), (nc, rs)
+
+                (x, aux_total), (nc, rs) = jax.lax.scan(
+                    scan_body, (x, aux_total), (jnp.arange(repeats), seg_params, seg_cache)
+                )
+                if rs:  # (repeats, B*S, k) per MoE layer of the pattern
+                    r = jnp.stack(rs, axis=1)
+                    routes.append(r.reshape(-1, *r.shape[2:]))
+                if new_caches is not None:
+                    new_caches.append(nc)
 
         x = Lyr.apply_norm(cfg, params["final_norm"], x)
         hidden = x if return_hidden else None
         logits = None
         if compute_logits:
             logits = self.unembed(params, x)
-        return ModelOutput(logits, new_caches, aux_total, hidden)
+        routes = jnp.concatenate(routes) if routes else None
+        return ModelOutput(logits, new_caches, aux_total, hidden, routes)
+
+    def _hold_experts(self, pattern, seg_params):
+        """Take the routed experts' stacked weights out of a scanned segment.
+
+        The scan would slice one layer's experts out of the stack each step,
+        and XLA copies such a slice whole before a Pallas call can read it
+        (3 x 369 MB a Moonlight layer, a quarter of a b32 forward on a v5e).
+        Held out, the expert layer gets the whole stack and its own index
+        (``ffn["layer"]``) and reads its experts in place
+        (`ops.expert_gmm`).  The EP path takes its shards as they come."""
+        if self.mesh_info is not None:
+            return seg_params, {}
+        held, rest = {}, []
+        for j, (spec, blk) in enumerate(zip(pattern, seg_params)):
+            if spec.ffn == "moe":
+                held[j] = {n: blk["ffn"][n] for n in Moe.STACKED}
+                blk = {**blk, "ffn": {k: v for k, v in blk["ffn"].items() if k not in held[j]}}
+            rest.append(blk)
+        return tuple(rest), held
 
     def unembed(self, params: Params, x: jax.Array) -> jax.Array:
         if self.cfg.tie_embeddings:

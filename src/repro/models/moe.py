@@ -1,9 +1,11 @@
-"""Mixture-of-Experts: dropless sort+ragged_dot local path and an
+"""Mixture-of-Experts: dropless sort + grouped-matmul local path and an
 expert-parallel (EP) shard_map path with capacity-bounded all_to_all.
 
 TPU adaptation notes (DESIGN.md Sec. 3): instead of a CUDA grouped-GEMM port
-we sort tokens by expert and use ``jax.lax.ragged_dot`` (MXU-friendly grouped
-matmul) for the local computation, and express expert parallelism as an
+we sort tokens by expert and run a grouped matmul for the local computation
+(`ops.expert_gmm`: the Pallas megablox kernel on a TPU, named
+``expert_gmm`` in the device trace; ``jax.lax.ragged_dot`` elsewhere and in
+the EP body), and express expert parallelism as an
 explicit shard_map: tokens sharded over the EP axes are routed to expert
 owners with a single capacity-padded ``all_to_all`` each way — the TPU-native
 analogue of the paper-ecosystem's NCCL all-to-all MoE dispatch.
@@ -17,7 +19,8 @@ import jax
 import jax.numpy as jnp
 
 from ..configs.base import ArchConfig
-from .layers import Params, _dense_init, dense, mlp_forward, mlp_init
+from ..kernels import ops
+from .layers import Params, _dense_init, mlp_forward, mlp_init
 
 
 @dataclasses.dataclass(frozen=True)
@@ -43,6 +46,10 @@ class MoEMeshInfo:
             object.__setattr__(self, "token_size", self.ep_size)
 
 
+# the routed experts' weights: (E_pad, d, f), (E_pad, f, d) and (E_pad, d, f)
+STACKED = ("w1", "w2", "w3")
+
+
 # --------------------------------------------------------------------- init
 def moe_init(key, cfg: ArchConfig, dtype, ep: int = 1) -> Params:
     """Expert weights stored stacked: (E_pad, d, f).  E padded to EP multiple."""
@@ -57,22 +64,37 @@ def moe_init(key, cfg: ArchConfig, dtype, ep: int = 1) -> Params:
         "w3": (jax.random.normal(ks[2], (E_pad, d, f)) * scale_in).astype(dtype),
         "w2": (jax.random.normal(ks[3], (E_pad, f, d)) * scale_out).astype(dtype),
     }
+    if cfg.router_score == "sigmoid_noaux":
+        # a dict of its own: under "router" `dense` would add it to the logits
+        p["score_bias"] = {"b": jnp.zeros((E_pad,), jnp.float32)}
     if cfg.n_shared_experts:
         p["shared"] = mlp_init(ks[4], d, f * cfg.n_shared_experts, dtype)
     return p
 
 
 def route(p: Params, cfg: ArchConfig, x: jax.Array) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """Router: top-k ids + renormalized gates + switch-style aux loss.
+    """Router: top-k ids, their gate weights and a load-balance aux loss.
+
+    Scores are the softmax of the f32 logits, or with ``cfg.router_score``
+    ``"sigmoid_noaux"`` a sigmoid per expert, whose top-k are chosen by score
+    plus the per-expert correction bias while the gates are the chosen scores
+    alone.  The gates are divided by their sum, then multiplied by
+    ``cfg.routed_scale``.
 
     x: (N, d) -> ids (N, k) int32, gates (N, k) f32, aux scalar.
     """
     E = cfg.n_experts
-    logits = dense(p["router"], x).astype(jnp.float32)
+    logits = jnp.dot(x, p["router"]["w"].astype(x.dtype), preferred_element_type=jnp.float32)
     logits = logits[..., :E]  # drop padding experts
-    probs = jax.nn.softmax(logits, axis=-1)
-    gates, ids = jax.lax.top_k(probs, cfg.top_k)
-    gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+    if cfg.router_score == "sigmoid_noaux":
+        scores = jax.nn.sigmoid(logits)
+        probs = scores / scores.sum(-1, keepdims=True)
+        _, ids = jax.lax.top_k(scores + p["score_bias"]["b"][:E], cfg.top_k)
+    else:
+        scores = probs = jax.nn.softmax(logits, axis=-1)
+        _, ids = jax.lax.top_k(scores, cfg.top_k)
+    gates = jnp.take_along_axis(scores, ids, axis=-1)
+    gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9) * cfg.routed_scale
     # load-balance loss: E * sum_e (fraction routed to e) * (mean prob of e)
     onehot = jax.nn.one_hot(ids, E, dtype=jnp.float32).sum(1)  # (N, E)
     frac = onehot.mean(0) / cfg.top_k
@@ -81,27 +103,36 @@ def route(p: Params, cfg: ArchConfig, x: jax.Array) -> tuple[jax.Array, jax.Arra
 
 
 # ------------------------------------------------------------- local (dropless)
-def expert_ffn_local(p: Params, cfg: ArchConfig, x: jax.Array) -> tuple[jax.Array, jax.Array]:
+def expert_ffn_local(
+    p: Params, cfg: ArchConfig, x: jax.Array
+) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Dropless MoE on one device: sort by expert, grouped matmul, unsort.
 
-    x: (N, d) -> (N, d), aux loss.
+    The experts' weights are this layer's, or with ``p["layer"]`` the stack
+    of every layer's, of which this layer is entry ``p["layer"]``.
+
+    x: (N, d) -> (N, d), aux loss, expert ids (N, k).
     """
     N, d = x.shape
     k = cfg.top_k
-    E_pad = p["w1"].shape[0]
-    ids, gates, aux = route(p, cfg, x)
-    flat_ids = ids.reshape(-1)  # (N*k,)
-    order = jnp.argsort(flat_ids)
-    token_of = order // k
-    xs = x[token_of]  # (N*k, d) sorted by expert
-    group_sizes = jnp.bincount(flat_ids, length=E_pad)
-    h1 = jax.lax.ragged_dot(xs, p["w1"].astype(x.dtype), group_sizes)
-    h3 = jax.lax.ragged_dot(xs, p["w3"].astype(x.dtype), group_sizes)
-    act = jax.nn.silu(h1) if cfg.act == "silu" else jax.nn.gelu(h1)
-    ys = jax.lax.ragged_dot(act * h3, p["w2"].astype(x.dtype), group_sizes)
-    w = gates.reshape(-1)[order].astype(x.dtype)
-    out = jnp.zeros((N, d), x.dtype).at[token_of].add(ys * w[:, None])
-    return out, aux
+    E_pad = p["w1"].shape[-3]
+    layer = p.get("layer")
+    with jax.named_scope("router"):
+        ids, gates, aux = route(p, cfg, x)
+    with jax.named_scope("experts"):
+        flat_ids = ids.reshape(-1)  # (N*k,)
+        order = jnp.argsort(flat_ids)
+        xs = x[order // k]  # (N*k, d) sorted by expert
+        group_sizes = jnp.bincount(flat_ids, length=E_pad)
+        h1 = ops.expert_gmm(xs, p["w1"], group_sizes, layer=layer)
+        h3 = ops.expert_gmm(xs, p["w3"], group_sizes, layer=layer)
+        act = jax.nn.silu(h1) if cfg.act == "silu" else jax.nn.gelu(h1)
+        ys = ops.expert_gmm(act * h3, p["w2"], group_sizes, layer=layer)
+        # unsort: (token, choice) slot i sits at row inv[i] of the sorted rows
+        inv = jnp.zeros_like(order).at[order].set(jnp.arange(order.size, dtype=order.dtype))
+        y = ys[inv].reshape(N, k, d).astype(jnp.float32) * gates[..., None]
+        out = y.sum(1).astype(x.dtype)
+    return out, aux, ids
 
 
 # --------------------------------------------------------------- EP shard_map
@@ -115,6 +146,7 @@ def expert_ffn_ep(
     expert owners over the flattened EP axes via capacity-padded all_to_all.
 
     x: (N_loc, d) local tokens.  Expert weights arrive sharded: (E_loc, d, f).
+    Returns (N_loc, d), the aux loss and the tokens' global expert ids.
     """
     ep = mesh_info.ep_size
     axes = mesh_info.ep_axes
@@ -173,7 +205,7 @@ def expert_ffn_ep(
     )
     # aux loss averaged over the whole mesh (fully replicated output)
     aux = jax.lax.pmean(aux, mesh_info.all_axes or axes)  # fully replicated
-    return out, aux
+    return out, aux, ids
 
 
 def moe_forward(
@@ -182,18 +214,20 @@ def moe_forward(
     x: jax.Array,  # (B, S, d)
     *,
     mesh_info: MoEMeshInfo | None = None,
-) -> tuple[jax.Array, jax.Array]:
+) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """-> (B, S, d), the aux loss, and the routes: expert ids (B*S, top_k)."""
     B, S, d = x.shape
     flat = x.reshape(B * S, d)
     if mesh_info is None:
-        y, aux = expert_ffn_local(p, cfg, flat)
+        y, aux, ids = expert_ffn_local(p, cfg, flat)
     elif mesh_info.mesh is None:
-        y, aux = expert_ffn_ep(p, cfg, flat, mesh_info)
+        y, aux, ids = expert_ffn_ep(p, cfg, flat, mesh_info)
     else:
-        y, aux = _moe_shard_mapped(p, cfg, flat, mesh_info)
+        y, aux, ids = _moe_shard_mapped(p, cfg, flat, mesh_info)
     if "shared" in p:
-        y = y + mlp_forward(p["shared"], flat, cfg.act)
-    return y.reshape(B, S, d), aux
+        with jax.named_scope("shared_experts"):
+            y = y + mlp_forward(p["shared"], flat, cfg.act)
+    return y.reshape(B, S, d), aux, ids
 
 
 def _moe_shard_mapped(
@@ -213,13 +247,14 @@ def _moe_shard_mapped(
         flat = jnp.concatenate([flat, jnp.zeros((pad, d), flat.dtype)], 0)
     ep_t = info.ep_axes if len(info.ep_axes) > 1 else info.ep_axes[0]
     tok_t = info.token_axes if len(info.token_axes) > 1 else info.token_axes[0]
-    p_ep = {k: p[k] for k in ("router", "w1", "w2", "w3")}
+    p_ep = {k: p[k] for k in ("router", "w1", "w2", "w3", "score_bias") if k in p}
     in_specs = (
         {
             "router": P(None, None),
             "w1": P(ep_t, None, None),
             "w2": P(ep_t, None, None),
             "w3": P(ep_t, None, None),
+            **({"score_bias": P(None)} if "score_bias" in p else {}),
         },
         P(tok_t, None),
     )
@@ -228,10 +263,10 @@ def _moe_shard_mapped(
         body,
         mesh=info.mesh,
         in_specs=in_specs,
-        out_specs=(P(tok_t, None), P()),
+        out_specs=(P(tok_t, None), P(), P(tok_t, None)),
         check_vma=False,
     )
-    y, aux = fn(p_ep, flat)
+    y, aux, ids = fn(p_ep, flat)
     if pad:
-        y = y[:N]
-    return y, aux
+        y, ids = y[:N], ids[:N]
+    return y, aux, ids

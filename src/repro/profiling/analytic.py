@@ -104,6 +104,26 @@ def param_count(cfg: ArchConfig, *, active: bool = False, embed: bool = True) ->
     return total
 
 
+def touched_params(cfg: ArchConfig, tokens: int) -> float:
+    """Parameters one batch of ``tokens`` tokens reads from memory.
+
+    A dense module reads every weight.  An MoE layer reads its router,
+    shared experts and attention, and of its routed experts those that at
+    least one of the ``tokens x top_k`` routes picks.  With routes spread
+    evenly and independently over the E experts, that is on average
+    ``E (1 - (1 - k / E) ** tokens)``: ``k`` for one token, and all E once
+    ``tokens x k`` far exceeds E (a 128-token prefill leaves each of 64
+    experts untouched with probability (58/64) ** 128, about 3e-6).
+    """
+    n = param_count(cfg, active=True)
+    if not cfg.is_moe_arch:
+        return n
+    E, k = cfg.n_experts, cfg.top_k
+    touched = E * (1.0 - (1.0 - k / E) ** tokens)
+    n_moe = sum(1 for s in cfg.layer_specs() if s.ffn == "moe")
+    return n + n_moe * (touched - k) * 3 * cfg.d_model * cfg.d_ff_expert
+
+
 def layer_flops_per_token(
     cfg: ArchConfig, spec: LayerSpec, seq: int, *, decode: bool = False
 ) -> float:
@@ -166,9 +186,10 @@ def module_duration(
     # efficiency ramps with batch: tiny batches stall the MXU
     mfu = base_mfu * min(1.0, 0.35 + 0.65 * (batch / 16.0) ** 0.5)
     compute_t = flops / (hw.peak_flops_bf16 * mfu)
-    # memory: weights stream once per batch; activations per token
-    n_params = param_count(cfg, active=True)
-    bytes_moved = 2.0 * n_params + tokens * cfg.d_model * 2.0 * (2 * cfg.n_layers)
+    # memory: the weights the batch touches stream once; activations per token
+    bytes_moved = (
+        2.0 * touched_params(cfg, tokens) + tokens * cfg.d_model * 2.0 * (2 * cfg.n_layers)
+    )
     mem_t = bytes_moved / hw.hbm_bw
     fixed = 30e-6  # launch/dispatch overhead
     return fixed + max(compute_t, mem_t)

@@ -38,7 +38,7 @@ def _mtp_loss(model: Model, params, hidden, tokens, labels) -> jax.Array:
     if cfg.mrope_sections is not None:
         pos = jnp.broadcast_to(pos, (3, B, S1))
     spec = LayerSpec("attn" if cfg.attn_kind != "mla" else "mla", "dense")
-    h, _, _ = _block_apply(params["mtp"]["block"], cfg, spec, h, pos, None, None, model.mesh_info)
+    h, *_ = _block_apply(params["mtp"]["block"], cfg, spec, h, pos, None, None, model.mesh_info)
     h = Lyr.apply_norm(cfg, params["mtp"]["norm"], h)
     if cfg.tie_embeddings:
         logits = h @ params["embed"]["w"].astype(h.dtype).T

@@ -230,3 +230,106 @@ def test_flash_decode_multi_kv_heads_untiled_length():
     out = flash_decode(q, kc, vc, lengths, interpret=True)
     exp = ref.decode_attention(q, kc, vc, lengths)
     assert rel_err(out, exp) < TOL[jnp.float32]
+
+
+def _attention_f64(q, k, v, scale):
+    """Causal softmax attention in float64, one head per query head."""
+    q, k, v = (np.asarray(t, np.float64) for t in (q, k, v))
+    s = np.einsum("bqhd,bkhd->bhqk", q, k) * scale
+    S = q.shape[1]
+    s = np.where(np.tril(np.ones((S, S), bool)), s, -np.inf)
+    p = np.exp(s - s.max(-1, keepdims=True))
+    p /= p.sum(-1, keepdims=True)
+    return np.einsum("bhqk,bkhd->bqhd", p, v)
+
+
+@pytest.mark.parametrize("S", [128, 64])
+def test_mla_prefill_attention_takes_xla_at_one_block(monkeypatch, S):
+    """MLA's prefill attention (q/k heads 128 + 64 = 192 wide, v heads 128)
+    goes through `attention`'s dispatch: XLA ops at stated precision where one
+    block holds the sequence, against a float64 attention."""
+    monkeypatch.setattr(ops, "_mode", lambda: "interpret")
+    monkeypatch.setattr(ops, "TAKEN", type(ops.TAKEN)())
+    B, H, dn, dr, dv = 2, 4, 128, 64, 128
+    ks = jax.random.split(jax.random.key(S), 5)
+    # bf16-exact float32 operands: what remains is the attention's own error
+    mk = lambda k, shape: jax.random.normal(k, shape).astype(jnp.bfloat16).astype(jnp.float32)
+    qn, qr = mk(ks[0], (B, S, H, dn)), mk(ks[1], (B, S, H, dr))
+    kn, kr, v = mk(ks[2], (B, S, H, dn)), mk(ks[3], (B, S, dr)), mk(ks[4], (B, S, H, dv))
+    scale = (dn + dr) ** -0.5
+    out = ops.mla_prefill_attention(qn, qr, kn, kr, v, scale=scale)
+    q = jnp.concatenate([qn, qr], -1)
+    k = jnp.concatenate([kn, jnp.broadcast_to(kr[:, :, None], (B, S, H, dr))], -1)
+    assert out.shape == (B, S, H, dv)
+    assert rel_err(out, _attention_f64(q, k, v, scale)) < TOL[jnp.float32]
+    assert dict(ops.TAKEN) == {("attention", "xla"): 1}
+
+
+def test_mla_prefill_attention_on_the_cpu_takes_ref(monkeypatch):
+    monkeypatch.setattr(ops, "TAKEN", type(ops.TAKEN)())
+    B, S, H = 1, 16, 2
+    ks = jax.random.split(jax.random.key(3), 5)
+    qn, kn, v = (jax.random.normal(kk, (B, S, H, 32)) for kk in ks[:3])
+    qr, kr = jax.random.normal(ks[3], (B, S, H, 16)), jax.random.normal(ks[4], (B, S, 16))
+    ops.mla_prefill_attention(qn, qr, kn, kr, v, scale=48 ** -0.5)
+    assert dict(ops.TAKEN) == {("attention", "ref"): 1}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("m,k,n,E", [(96, 64, 48, 8), (256, 128, 128, 16), (60, 32, 16, 4)])
+def test_expert_gmm_matches_ragged_dot(m, k, n, E, dtype):
+    """The megablox grouped matmul (interpret mode) against ragged_dot, with
+    empty groups and a row count that is not a multiple of 8."""
+    from repro.kernels.expert_gmm import gmm
+
+    ks = jax.random.split(jax.random.key(m), 3)
+    x = jax.random.normal(ks[0], (m, k), dtype)
+    w = (jax.random.normal(ks[1], (E, k, n)) * k ** -0.5).astype(dtype)
+    sizes = jnp.bincount(jax.random.randint(ks[2], (m,), 0, E // 2) * 2, length=E)  # odd experts empty
+    out = gmm(x, w, sizes.astype(jnp.int32), interpret=True)
+    assert out.shape == (m, n) and out.dtype == dtype
+    assert rel_err(out, jax.lax.ragged_dot(x, w, sizes)) < TOL[dtype]
+
+
+def test_expert_gmm_tiling_fits_scoped_vmem():
+    from repro.kernels.expert_gmm import tiling
+
+    # Moonlight's widths: one m tile of 256 rows against an expert's whole
+    # (k, n), the fastest tiling measured on a v5e (PERF.md)
+    for m in (768, 6144, 24576):
+        assert tiling(m, 2048, 1408) == (256, 2048, 1408)
+        assert tiling(m, 1408, 2048) == (256, 1408, 2048)
+    # narrower widths keep 512-row tiles; a width too wide for one tile halves n
+    assert tiling(1024, 512, 512) == (512, 512, 512)
+    tm, tk, tn = tiling(4096, 4096, 4096)
+    assert 4096 % tm == 0 and tk == 4096 and 4096 % tn == 0 and tn < 4096
+
+
+def test_expert_gmm_dispatch_and_gradient(monkeypatch):
+    """`ops.expert_gmm` counts its path; on the kernel path its gradient is
+    ragged_dot's."""
+    monkeypatch.setattr(ops, "TAKEN", type(ops.TAKEN)())
+    ks = jax.random.split(jax.random.key(9), 2)
+    x, w = jax.random.normal(ks[0], (32, 16)), jax.random.normal(ks[1], (4, 16, 8))
+    sizes = jnp.asarray([8, 0, 16, 8], jnp.int32)
+    loss = lambda f: lambda x, w: jnp.sum(f(x, w, sizes) ** 2)
+    want = jax.grad(loss(jax.lax.ragged_dot), argnums=(0, 1))(x, w)
+    assert rel_err(ops.expert_gmm(x, w, sizes), jax.lax.ragged_dot(x, w, sizes)) == 0.0
+    monkeypatch.setattr(ops, "_mode", lambda: "interpret")
+    got = jax.grad(loss(ops.expert_gmm), argnums=(0, 1))(x, w)
+    for g, e in zip(got, want):
+        assert rel_err(g, e) < TOL[jnp.float32]
+    assert dict(ops.TAKEN) == {("expert_gmm", "ref"): 1, ("expert_gmm", "interpret"): 1}
+
+
+@pytest.mark.parametrize("mode", ["ref", "interpret"])
+def test_expert_gmm_reads_one_layer_of_a_stack(monkeypatch, mode):
+    """With ``layer``, the product is with that layer's experts of the
+    (L, E, k, n) stack, on either path."""
+    monkeypatch.setattr(ops, "_mode", lambda: mode)
+    ks = jax.random.split(jax.random.key(4), 2)
+    x, w = jax.random.normal(ks[0], (40, 16)), jax.random.normal(ks[1], (3, 4, 16, 8))
+    sizes = jnp.asarray([16, 0, 8, 16], jnp.int32)
+    for layer in range(3):
+        got = jax.jit(lambda x, w, i: ops.expert_gmm(x, w, sizes, layer=i))(x, w, layer)
+        assert rel_err(got, jax.lax.ragged_dot(x, w[layer], sizes)) < TOL[jnp.float32]

@@ -30,11 +30,37 @@ def test_route_shapes_and_normalization():
     assert float(aux) > 0  # switch aux loss is >= 1 for any routing
 
 
+SIG = SMOKE_ARCHS["moonlight-16b-a3b"]
+
+
+def test_sigmoid_route_selects_by_bias_and_weighs_without_it():
+    """noaux_tc routing: the top-k of score + bias are chosen; their weights
+    are the scores alone over their sum, times the routed scale."""
+    p = moe_init(jax.random.key(0), SIG, jnp.float32)
+    p["score_bias"]["b"] = jax.random.normal(jax.random.key(2), (SIG.n_experts,)) * 0.3
+    x = jax.random.normal(jax.random.key(1), (12, SIG.d_model))
+    ids, gates, _ = route(p, SIG, x)
+    s = np.asarray(jax.nn.sigmoid(x @ p["router"]["w"]))
+    biased = s + np.asarray(p["score_bias"]["b"])
+    for i in range(x.shape[0]):
+        want = np.argsort(-biased[i])[: SIG.top_k]
+        assert set(np.asarray(ids[i]).tolist()) == set(want.tolist())
+        chosen = s[i, np.asarray(ids[i])]
+        np.testing.assert_allclose(
+            np.asarray(gates[i]), chosen / chosen.sum() * SIG.routed_scale, rtol=1e-5
+        )
+    # the bias moved some choice: the unbiased top-k differs somewhere
+    assert any(
+        set(np.argsort(-s[i])[: SIG.top_k].tolist()) != set(np.asarray(ids[i]).tolist())
+        for i in range(x.shape[0])
+    )
+
+
 def test_local_path_matches_explicit_expert_loop():
     """sort+ragged_dot == gather-per-expert dense reference."""
     p = moe_init(jax.random.key(0), CFG, jnp.float32)
     x = jax.random.normal(jax.random.key(1), (16, CFG.d_model)) * 0.5
-    out, _ = expert_ffn_local(p, CFG, x)
+    out, _, _ = expert_ffn_local(p, CFG, x)
 
     ids, gates, _ = route(p, CFG, x)
     expected = np.zeros_like(np.asarray(x))
@@ -54,7 +80,7 @@ def test_moe_grads_flow_through_ragged_dot():
     x = jax.random.normal(jax.random.key(1), (2, 8, CFG.d_model)) * 0.5
 
     def loss(p):
-        y, aux = moe_forward(p, CFG, x)
+        y, aux, _ = moe_forward(p, CFG, x)
         return jnp.sum(y ** 2) + 0.01 * aux
 
     g = jax.grad(loss)(p)
@@ -62,6 +88,51 @@ def test_moe_grads_flow_through_ragged_dot():
         leaf = g[k]["w"] if isinstance(g[k], dict) else g[k]
         assert float(jnp.abs(leaf).sum()) > 0, k
         assert bool(jnp.all(jnp.isfinite(leaf))), k
+
+
+def _scanned_moe():
+    """Moonlight's SMOKE model: a dense layer, then two expert layers scanned."""
+    from repro.models import Model
+
+    model = Model(SIG)
+    assert [r for _, r in model.segments] == [1, 2]
+    params = model.init(jax.random.key(0))
+    toks = jax.random.randint(jax.random.key(1), (2, 8), 0, SIG.vocab_size)
+    return model, params, toks
+
+
+def test_held_experts_forward_equals_the_scanned_slices():
+    """Reading each layer's experts in place from the stack computes what the
+    scan's per-layer slices compute: the same logits and routes."""
+    model, params, toks = _scanned_moe()
+    sliced = model.forward(params, toks)
+    logits, routes = jax.jit(
+        lambda p, t: (lambda o: (o.logits, o.routes))(model.forward(p, t, hold_experts=True))
+    )(params, toks)
+    assert bool(jnp.all(routes == sliced.routes))
+    assert routes.shape == (2, 2 * 8, SIG.top_k)
+    np.testing.assert_allclose(np.asarray(logits), np.asarray(sliced.logits), rtol=1e-5, atol=1e-5)
+
+
+def test_grads_through_the_scanned_moe_forward():
+    """A gradient through a scanned MoE segment (the training forward, which
+    slices each layer's experts out of the stack) reaches every layer's
+    experts, and equals the gradient through the in-place read."""
+    model, params, toks = _scanned_moe()
+
+    def loss(p, hold):
+        out = model.forward(p, toks, hold_experts=hold)
+        return jnp.mean(out.logits ** 2) + 0.01 * out.aux_loss
+
+    g = jax.jit(lambda p: jax.grad(loss)(p, False))(params)
+    g_held = jax.jit(lambda p: jax.grad(loss)(p, True))(params)
+    ffn = g["segments"][1][0]["ffn"]
+    assert ffn["w1"].shape == params["segments"][1][0]["ffn"]["w1"].shape
+    for name in ("w1", "w2", "w3"):
+        per_layer = jnp.abs(ffn[name]).reshape(2, -1).sum(-1)
+        assert bool(jnp.all(per_layer > 0)), name
+    for a, b in zip(jax.tree.leaves(g), jax.tree.leaves(g_held)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-6)
 
 
 _EP_SCRIPT = textwrap.dedent(
@@ -78,7 +149,7 @@ _EP_SCRIPT = textwrap.dedent(
     cfg = SMOKE_ARCHS["qwen2-moe-a2.7b"].replace(moe_capacity_factor=8.0)
     p = moe_init(jax.random.key(0), cfg, jnp.float32, ep=4)
     x = jax.random.normal(jax.random.key(1), (2, 8, cfg.d_model)) * 0.5
-    y_local, _ = moe_forward(p, cfg, x)
+    y_local, _, ids_local = moe_forward(p, cfg, x)
 
     mesh = Mesh(np.array(jax.devices()).reshape(2, 4), ("data", "model"))
     info = MoEMeshInfo(
@@ -87,7 +158,8 @@ _EP_SCRIPT = textwrap.dedent(
         mesh=mesh, all_axes=("data", "model"),
     )
     with mesh:
-        y_ep, _ = jax.jit(lambda p, x: moe_forward(p, cfg, x, mesh_info=info))(p, x)
+        y_ep, _, ids_ep = jax.jit(lambda p, x: moe_forward(p, cfg, x, mesh_info=info))(p, x)
+    assert bool((ids_ep == ids_local).all())
     err = float(jnp.max(jnp.abs(y_ep - y_local)) / (jnp.max(jnp.abs(y_local)) + 1e-9))
     assert err < 1e-5, err
     print("EP-OK", err)
@@ -118,5 +190,5 @@ def test_capacity_drop_degrades_gracefully():
 
     # ep_size=1: all_to_all over a single "axis" degenerates; use local path
     # with an artificially low capacity via the EP body on one device
-    out, aux = expert_ffn_local(p, cfg, x)
+    out, aux, _ = expert_ffn_local(p, cfg, x)
     assert bool(jnp.all(jnp.isfinite(out)))

@@ -2,6 +2,7 @@
 import pytest
 
 from repro.configs import ARCHS
+from repro.profiling.analytic import touched_params
 from repro.profiling import (
     arch_profile,
     flops_per_token,
@@ -20,6 +21,7 @@ PUBLISHED = {
     "gemma3-1b": (1.0, 1.0),
     "qwen2-moe-a2.7b": (14.3, 2.7),
     "qwen1.5-4b": (3.95, 3.95),
+    "moonlight-16b-a3b": (16.0, 3.0),
 }
 
 
@@ -97,3 +99,40 @@ def test_spec_for_device_kind(kind, spec):
 def test_spec_for_unknown_kind_raises(kind):
     with pytest.raises(KeyError, match="no peaks"):
         spec_for(kind)
+
+
+def test_dense_modules_price_as_before():
+    """A dense module reads every weight at any batch: the duration is the
+    one its active parameter count gave, so the dense cells' plans stay."""
+    for arch in ("smollm-360m", "qwen1.5-4b"):
+        cfg = ARCHS[arch]
+        for b in (1, 8, 32):
+            assert touched_params(cfg, b * 128) == param_count(cfg, active=True)
+    cfg, hw, b = ARCHS["smollm-360m"], TPU_V5E, 4
+    flops = flops_per_token(cfg, 128) * b * 128
+    mfu = 0.55 * min(1.0, 0.35 + 0.65 * (b / 16.0) ** 0.5)
+    mem = (2.0 * param_count(cfg, active=True) + b * 128 * cfg.d_model * 2.0 * 2 * cfg.n_layers) / hw.hbm_bw
+    assert module_duration(cfg, b, 128, hw) == 30e-6 + max(flops / (hw.peak_flops_bf16 * mfu), mem)
+
+
+def test_moe_weight_reads_count_every_touched_expert():
+    """One token reads its top-k experts; a 128-token prefill reads all of
+    them (each of 64 left out with probability (58/64)^128)."""
+    cfg = ARCHS["moonlight-16b-a3b"].replace(n_layers=9)
+    active, total = param_count(cfg, active=True), param_count(cfg)
+    assert touched_params(cfg, 1) == pytest.approx(active)
+    assert touched_params(cfg, 128) == pytest.approx(total, rel=1e-5)
+    n = [touched_params(cfg, t) for t in (1, 2, 4, 8, 16)]
+    assert all(a < b < total for a, b in zip(n, n[1:]))
+    # two tokens: E (1 - (1 - k/E)^2) = 11.4375 experts of a layer on average
+    per_expert = 3 * cfg.d_model * cfg.d_ff_expert
+    assert touched_params(cfg, 2) - active == pytest.approx(8 * (11.4375 - 6) * per_expert)
+
+
+def test_moe_b1_prefill_prices_the_whole_module():
+    """At b1 x 128 tokens the 9-layer Moonlight stage streams all 5.43B
+    parameters (10.9 GB), not the 1.42B active ones: 13.3 ms on a v5e."""
+    cfg = ARCHS["moonlight-16b-a3b"].replace(n_layers=9)
+    d = module_duration(cfg, 1, 128, TPU_V5E)
+    assert d == pytest.approx(30e-6 + 2 * param_count(cfg) / TPU_V5E.hbm_bw, rel=1e-3)
+    assert d > 3.5 * (2 * param_count(cfg, active=True) / TPU_V5E.hbm_bw)

@@ -42,6 +42,34 @@ def test_serve_real_smoke_pipeline_end_to_end(cache_in):
             assert bool(jnp.isfinite(logits).all())
 
 
+def test_serve_real_smoke_moe_returns_routes(cache_in):
+    """The MLA + sigmoid-routed MoE module on the served path: each forward
+    returns its logits and the routes the timed call itself produced."""
+    run = serve.main([
+        "--arch", "moonlight-16b-a3b", "--real", "--smoke", "--pipeline",
+        "--requests", "40", "--seq", "16", "--rate", "50",
+    ])
+    res = run.result
+    assert len(res.e2e_latencies) + res.shed + res.dropped == res.offered == 40
+    ex = run.executors["moonlight-16b-a3b"]
+    cfg = ex.cfg
+    for b in serve.plan_batches(run.plan)["moonlight-16b-a3b"]:
+        assert ("moonlight-16b-a3b", b) in run.live.measured
+        logits, routes = ex(b)
+        assert logits.shape == (b, 16, cfg.vocab_size)
+        assert routes.shape == (cfg.n_layers - cfg.n_dense_layers, b * 16, cfg.top_k)
+        assert routes.dtype == jnp.int32
+        assert bool(((routes >= 0) & (routes < cfg.n_experts)).all())
+
+
+def test_dense_executor_returns_logits_alone(cache_in):
+    from repro.configs import get_config
+
+    ex = serve.ModuleExecutor(get_config("smollm-360m", smoke=True), seq=8)
+    out = ex(2)
+    assert isinstance(out, jax.Array) and out.shape == (2, 8, ex.cfg.vocab_size)
+
+
 def test_serve_real_without_smoke_refuses_cpu(cache_in):
     with pytest.raises(SystemExit) as e:
         serve.main(["--arch", "smollm-360m", "--real", "--requests", "4"])
