@@ -113,7 +113,7 @@ def expert_ffn_local(
 
     x: (N, d) -> (N, d), aux loss, expert ids (N, k).
     """
-    N, d = x.shape
+    N = x.shape[0]
     k = cfg.top_k
     E_pad = p["w1"].shape[-3]
     layer = p.get("layer")
@@ -128,10 +128,17 @@ def expert_ffn_local(
         h3 = ops.expert_gmm(xs, p["w3"], group_sizes, layer=layer)
         act = jax.nn.silu(h1) if cfg.act == "silu" else jax.nn.gelu(h1)
         ys = ops.expert_gmm(act * h3, p["w2"], group_sizes, layer=layer)
-        # unsort: (token, choice) slot i sits at row inv[i] of the sorted rows
-        inv = jnp.zeros_like(order).at[order].set(jnp.arange(order.size, dtype=order.dtype))
-        y = ys[inv].reshape(N, k, d).astype(jnp.float32) * gates[..., None]
-        out = y.sum(1).astype(x.dtype)
+        with jax.named_scope("combine"):
+            # un-sort: (token, choice) slot i sits at row inv[i] of the sorted rows.
+            # Gathered in (choice, token) order and summed one choice at a time in
+            # f32, the un-sort, gate weighting and sum compile to one pass over the
+            # gathered rows, with no f32 or k-padded (N, k, d) copy of them.
+            inv = jnp.zeros_like(order).at[order].set(jnp.arange(order.size, dtype=order.dtype))
+            yk = ys[inv.reshape(N, k).T]  # (k, N, d)
+            y = yk[0].astype(jnp.float32) * gates[:, 0, None]
+            for j in range(1, k):
+                y = y + yk[j].astype(jnp.float32) * gates[:, j, None]
+            out = y.astype(x.dtype)
     return out, aux, ids
 
 

@@ -13,6 +13,7 @@ import pytest
 pytestmark = pytest.mark.slow
 
 from repro.configs import SMOKE_ARCHS
+from repro.kernels import ops
 from repro.models.moe import expert_ffn_local, moe_forward, moe_init, route
 
 
@@ -73,6 +74,52 @@ def test_local_path_matches_explicit_expert_loop():
             y = (act * h3) @ np.asarray(p["w2"][e])
             expected[i] += float(gates[i, j]) * y
     np.testing.assert_allclose(np.asarray(out), expected, rtol=2e-4, atol=2e-4)
+
+
+def _padded_weighted_sum(ys, order, gates, dtype):
+    """The combine as an (N, k, d) f32 product summed over k."""
+    N, k = gates.shape
+    inv = jnp.zeros_like(order).at[order].set(jnp.arange(order.size, dtype=order.dtype))
+    y = ys[inv].reshape(N, k, -1).astype(jnp.float32) * gates[..., None]
+    return y.sum(1).astype(dtype)
+
+
+def _bf16_ulp(v):
+    """The spacing of bf16 numbers at each |v| (8 significant bits)."""
+    tiny = float(jnp.finfo(jnp.bfloat16).tiny)
+    e = np.floor(np.log2(np.maximum(np.abs(v), tiny)))
+    return np.exp2(e - 7)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("N", [13, 37])
+@pytest.mark.parametrize("k", [2, 4, 6, 8])
+def test_combine_equals_the_padded_weighted_sum(k, N, dtype):
+    """The one-pass combine gives the (N, k, d) weighted sum of the same
+    expert outputs, routes and gates, to within one bf16 ulp.  N is odd, so
+    N*k is no multiple of 8 below k = 8."""
+    cfg = SIG.replace(top_k=k)
+    p = moe_init(jax.random.key(0), cfg, dtype)
+    x = (jax.random.normal(jax.random.key(1), (N, cfg.d_model)) * 0.5).astype(dtype)
+
+    @jax.jit
+    def both(p, x):
+        out, _, ids = expert_ffn_local(p, cfg, x)
+        _, gates, _ = route(p, cfg, x)
+        flat = ids.reshape(-1)
+        order = jnp.argsort(flat)
+        xs = x[order // k]
+        sizes = jnp.bincount(flat, length=p["w1"].shape[0])
+        h1 = ops.expert_gmm(xs, p["w1"], sizes)
+        h3 = ops.expert_gmm(xs, p["w3"], sizes)
+        ys = ops.expert_gmm(jax.nn.silu(h1) * h3, p["w2"], sizes)
+        return out, _padded_weighted_sum(ys, order, gates, dtype)
+
+    out, want = both(p, x)
+    want = np.asarray(want, np.float32)
+    got = np.asarray(out, np.float32)
+    assert out.dtype == dtype and got.shape == want.shape
+    assert np.all(np.abs(got - want) <= _bf16_ulp(want)), np.max(np.abs(got - want))
 
 
 def test_moe_grads_flow_through_ragged_dot():

@@ -7,15 +7,20 @@ chip's compiler refuses, which interpret mode cannot.  The topology is
 described only inside the fixture, so collection is identical on every
 worker and only the worker that runs this file loads the TPU compiler.
 """
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 
+from repro.configs import ARCHS
+from repro.kernels import ops
 from repro.kernels.decode_attention import flash_decode
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.rmsnorm import fused_rmsnorm
+from repro.models.moe import expert_ffn_local
 
 
 @pytest.fixture(scope="module")
@@ -118,3 +123,33 @@ def test_expert_gmm_compiles_at_moonlight_widths(one_chip, rows, k, n):
     calls = [ln for ln in txt.splitlines() if 'custom_call_target="tpu_custom_call"' in ln]
     assert len(calls) == 1
     assert calls[0].split(" = ")[0].split()[-1].startswith("%expert_gmm.")
+
+
+@pytest.mark.parametrize("N", [32 * 128, 8 * 128], ids=["moonlight-b32", "moonlight-b8"])
+def test_moe_combine_compiles_without_the_padded_copy(one_chip, monkeypatch, N):
+    """The local MoE layer at Moonlight's widths combines its expert outputs
+    with no f32 copy of them and no (N, k, d) array, and keeps its three
+    grouped matmuls."""
+    monkeypatch.setattr(ops, "_mode", lambda: "tpu")  # the kernels, as on the chip
+    cfg = ARCHS["moonlight-16b-a3b"]
+    d, f, E, k = cfg.d_model, cfg.d_ff_expert, cfg.n_experts, cfg.top_k
+    S = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+    p = {
+        "router": {"w": S((d, E), BF16)},
+        "score_bias": {"b": S((E,), jnp.float32)},
+        "w1": S((E, d, f), BF16),
+        "w3": S((E, d, f), BF16),
+        "w2": S((E, f, d), BF16),
+    }
+    txt = (
+        jax.jit(lambda p, x: expert_ffn_local(p, cfg, x)[0])
+        .lower(p, S((N, d), BF16)).compile().as_text()
+    )
+    for dt, dims in re.findall(r"\b(\w+)\[(\d+(?:,\d+)*)\]", txt):
+        shape = tuple(int(v) for v in dims.split(","))
+        assert shape != (N, k, d), (dt, shape)
+        assert not (dt == "f32" and len(shape) > 1 and N * k * d == math.prod(shape)), shape
+    calls = [ln for ln in txt.splitlines() if 'custom_call_target="tpu_custom_call"' in ln]
+    names = [ln.split(" = ")[0].split()[-1] for ln in calls]
+    assert len(names) == 3 and all(n.startswith("%expert_gmm.") for n in names), names
+
