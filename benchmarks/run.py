@@ -314,7 +314,7 @@ def bench_shed_sweep(n: int) -> None:
             res = eng.run(
                 600, frame_rate, arrivals="mmpp", seed=0,
                 timeout="budget", frontend=fe,
-                offered_rate=1.3 * frame_rate, pipeline=True,
+                offered_rate=1.3 * frame_rate,
             )
             rep = res.miss_report()
             if not rep.conserved:
@@ -355,8 +355,8 @@ def bench_shed_sweep(n: int) -> None:
 
 def bench_pipeline_sweep(n: int) -> None:
     """Pipelined end-to-end p99 vs the analytic critical-path WCL sum, per
-    latency splitter.  The multi-module co-simulation (engine
-    ``pipeline=True``) is the first honest end-to-end check of the splitter
+    latency splitter.  The multi-module co-simulation (the engine's one
+    served path) is the first honest end-to-end check of the splitter
     budgets: every frame traverses the DAG through real batch formation, so
     p99/WCL-sum near 1.0 means the per-module budget assignment survives
     cross-stage hand-off; the mean sits below it by the batch-collection
@@ -378,7 +378,7 @@ def bench_pipeline_sweep(n: int) -> None:
             plan = Planner(opts).plan(wl, PROFILES)
             if not plan.feasible:
                 continue
-            res = ServingEngine(plan).run(n_frames, rate, pipeline=True)
+            res = ServingEngine(plan).run(n_frames, rate)
             wcl_sum = plan.e2e_latency
             ratios.append(res.p99 / wcl_sum)
             means.append(
@@ -456,7 +456,7 @@ def bench_diurnal_sweep(n: int) -> None:
             emit(f"diurnal_sweep_{name}", 0.0, "infeasible", app=name, feasible=False)
             continue
         res_pk = ServingEngine(plan_pk).run(
-            n_frames, rate * peak, arrivals=arr, frontend=fe, pipeline=True,
+            n_frames, rate * peak, arrivals=arr, frontend=fe,
             timeout="budget",
         )
         att = lambda r: float(
@@ -469,7 +469,7 @@ def bench_diurnal_sweep(n: int) -> None:
                 interval=period / div, profiles=PROFILES, margin=0.25
             )
             res = ServingEngine(plan).run(
-                n_frames, rate, arrivals=arr, frontend=fe, pipeline=True,
+                n_frames, rate, arrivals=arr, frontend=fe,
                 control=ctrl, timeout="budget",
             )
             cost_rp = serving_cost(res.epochs, float(arr[-1]))
@@ -594,7 +594,7 @@ def bench_pipeline_speed(n: int) -> None:
         repeat=1 if SMOKE else 2,
     )
     fast, us_fast = common.timed(
-        lambda: eng.run(n_frames, rate, arrivals="poisson", pipeline=True),
+        lambda: eng.run(n_frames, rate, arrivals="poisson"),
         repeat=3,
     )
     t_ref, t_fast = us_ref / 1e6, us_fast / 1e6
@@ -649,7 +649,6 @@ def bench_pipeline_speed(n: int) -> None:
     fast_b, us_fast_b = common.timed(
         lambda: eng.run(
             n_burst, rate, arrivals="poisson", frontend=fe, timeout="budget",
-            pipeline=True,
         ),
         repeat=1,
     )
@@ -683,7 +682,7 @@ def bench_pipeline_speed(n: int) -> None:
     # run — must stay bit-exact and (smoke gate) within 10% of untraced
     traced, us_obs = common.timed(
         lambda: eng.run(
-            n_frames, rate, arrivals="poisson", pipeline=True,
+            n_frames, rate, arrivals="poisson",
             observability=ObservabilityConfig(sample=0.1),
         ),
         repeat=3,
@@ -727,7 +726,6 @@ def bench_pipeline_speed(n: int) -> None:
         res_t = eng.run(
             n_t, rate, arrivals=arr, timeout="budget",
             frontend=FrontendConfig(dummies=True, burst_deadline=True),
-            pipeline=True,
             control=ControlLoopConfig(
                 interval=period / 6, profiles=PROFILES, margin=0.25
             ),
@@ -1160,7 +1158,7 @@ def bench_chaos_sweep(n: int) -> None:
             interval=interval, profiles=PROFILES, margin=0.35
         )
         kw = dict(
-            arrivals=arr, frontend=fe, pipeline=True, timeout="budget",
+            arrivals=arr, frontend=fe, timeout="budget",
         )
         base = ServingEngine(plan).run(n_frames, rate, control=ctrl(), **kw)
         off = ServingEngine(plan).run(
@@ -1268,7 +1266,7 @@ def bench_chaos_sweep(n: int) -> None:
                 n_frames, rate,
                 arrivals=arr,
                 frontend=FrontendConfig(dummies=True, burst_deadline=True),
-                pipeline=True, timeout="budget",
+                timeout="budget",
                 control=ControlLoopConfig(
                     interval=interval, profiles=PROFILES, margin=0.35
                 ),

@@ -10,7 +10,7 @@ and the script exits non-zero without printing a result:
 2. kernels: each Pallas kernel on the chip against `repro.kernels.ref` at
    the served shapes, within the bf16 tolerance of tests/test_kernels.py.
 3. main path: ``python -m repro.launch.serve --arch smollm-360m,gemma3-1b
-   --real --pipeline --seq 128`` (the `serve.main` a user calls), planned
+   --real --seq 128`` (the `serve.main` a user calls), planned
    on the attached chip's profile and served through `ServingEngine` with
    `LiveServiceTime`; every forward must contain a Pallas kernel
    (``tpu_custom_call``), RMSNorm must take its kernel and attention the
@@ -45,7 +45,7 @@ REQUESTS = 400
 # feasible on the v5e-only analytic plan, with two batch sizes for gemma3
 RATE, SLO = 500.0, 0.5
 SERVE_ARGV = [
-    "--arch", ",".join(ARCHS), "--real", "--pipeline", "--seq", str(SEQ),
+    "--arch", ",".join(ARCHS), "--real", "--seq", str(SEQ),
     "--rate", str(RATE), "--slo", str(SLO), "--requests", str(REQUESTS),
 ]
 BF16_TOL = 2e-2  # tests/test_kernels.py TOL[bfloat16]

@@ -161,7 +161,7 @@ def main() -> None:
         diurnal = trace_arrivals(n, args.rate, seed=0, period=period)
         fe = FrontendConfig(dummies=True)
         loop = ServingEngine(plan).run(
-            n, args.rate, arrivals=diurnal, frontend=fe, pipeline=True,
+            n, args.rate, arrivals=diurnal, frontend=fe,
             control=ControlLoopConfig(
                 interval=period / 48, profiles=profiles, margin=0.25
             ),
@@ -177,7 +177,7 @@ def main() -> None:
         )
         if plan_peak.feasible:
             static = ServingEngine(plan_peak).run(
-                n, 1.8 * args.rate, arrivals=diurnal, frontend=fe, pipeline=True
+                n, 1.8 * args.rate, arrivals=diurnal, frontend=fe
             )
             print(
                 f"  static peak: cost {plan_peak.cost:7.2f}  attain {100 * static.attainment:5.1f}%"

@@ -1,28 +1,29 @@
 """Serving launcher: plan with Harpagon, then serve batched requests.
 
 Plans a (possibly multi-module) session over the analytic TPU profiles and
-runs the serving engine.  With --real, every batch is a jitted forward of
-the module at published widths on the attached TPU (seeded random weights
-and token ids), planned on that chip's profile only; --real --smoke runs
-the reduced configs instead, which is how CPU tests drive the path.
-Without --real, profiled durations drive an event simulation.
+serves it through the pipelined DAG co-simulation.  With --real, every
+batch is a jitted forward of the module at published widths on the
+attached TPU (seeded random weights and token ids), planned on that chip's
+profile only, and batch service times are the measured forwards; --real
+--smoke runs the reduced configs instead, which is how CPU tests drive the
+path.  Without --real, profiled durations drive the event simulation.
 
   python -m repro.launch.serve --arch smollm-360m,gemma3-1b --real \
-      --pipeline --seq 128            # the chip path (`chip_smoke.py`)
+      --seq 128                       # the chip path (`chip_smoke.py`)
 
   PYTHONPATH=src python -m repro.launch.serve --arch smollm-360m \
       --rate 200 --slo 0.5 --requests 2000
   PYTHONPATH=src python -m repro.launch.serve --arch gemma3-1b,qwen1.5-4b \
       --rate 120 --slo 1.0            # two-module chain
   PYTHONPATH=src python -m repro.launch.serve --arch smollm-360m --real \
-      --pipeline --epoch 2.0          # pipelined co-sim against measured
-                                      # step times + epoch audit/replan
+      --epoch 2.0                     # co-sim against measured step
+                                      # times + epoch audit/replan
   PYTHONPATH=src python -m repro.launch.serve --arch smollm-360m \
-      --pipeline --epoch 2.0 --arrivals diurnal --trace trace.json
+      --epoch 2.0 --arrivals diurnal --trace trace.json
                                       # observability on: per-epoch metrics,
                                       # SLO-miss forensics, Perfetto trace
   PYTHONPATH=src python -m repro.launch.serve --arch smollm-360m \
-      --pipeline --epoch 2.0 --chaos  # one seeded machine crash per epoch:
+      --epoch 2.0 --chaos             # one seeded machine crash per epoch:
                                       # watchdog detection, re-queue recovery,
                                       # failure replan + warm-spare promotion
 """
@@ -193,7 +194,6 @@ def _serve_pool(args, archs, profiles) -> None:
         args.requests,
         args.rate,
         arrivals=arrivals,
-        pipeline=True,
         control=control,
         observability=args.trace is not None,
         faults=_make_faults(args),
@@ -230,11 +230,6 @@ def main(argv=None) -> "ServeRun | None":
     )
     ap.add_argument("--compare", action="store_true", help="plan with all 5 systems")
     ap.add_argument(
-        "--pipeline", action="store_true",
-        help="serve through the pipelined DAG co-simulation (with --real, "
-        "batch service times are measured executor forwards)",
-    )
-    ap.add_argument(
         "--epoch", type=float, default=0.0,
         help="control-loop epoch interval in seconds (0 = control off); "
         "with --real each epoch audits modeled vs measured service time "
@@ -256,7 +251,7 @@ def main(argv=None) -> "ServeRun | None":
     ap.add_argument(
         "--chaos", type=float, nargs="?", const=0.0, default=None,
         metavar="MTBF",
-        help="seeded fault injection (requires --pipeline): machine crashes "
+        help="seeded fault injection: machine crashes "
         "with the given mean-time-between-failures in seconds (omit the "
         "value for one crash per epoch with --epoch, or one mid-run crash "
         "without it) — exercises watchdog detection, frame-conserving "
@@ -270,12 +265,6 @@ def main(argv=None) -> "ServeRun | None":
         "at https://ui.perfetto.dev",
     )
     args = ap.parse_args(argv)
-    if args.epoch and not args.pipeline:
-        ap.error("--epoch requires --pipeline (the control loop lives in "
-                 "the pipelined serving loop)")
-    if args.chaos is not None and not args.pipeline:
-        ap.error("--chaos requires --pipeline (faults fire as events in "
-                 "the pipelined serving loop)")
 
     archs = args.arch.split(",")
     hardware = tuple(CATALOG)
@@ -329,8 +318,7 @@ def main(argv=None) -> "ServeRun | None":
                 executors[m](b)
                 print(f"setup: compiled {m} b{b} in "
                       f"{executors[m].compile_s[b]:.3f}s")
-        if args.pipeline:
-            live = LiveServiceTime(executors)
+        live = LiveServiceTime(executors)
 
     engine = ServingEngine(plan, executors=executors)
     control = (
@@ -351,7 +339,6 @@ def main(argv=None) -> "ServeRun | None":
         args.requests,
         args.rate,
         arrivals=arrivals,
-        pipeline=args.pipeline,
         control=control,
         service_time=live,
         observability=args.trace is not None,
@@ -388,8 +375,7 @@ def main(argv=None) -> "ServeRun | None":
     if args.trace is not None:
         if res.metrics is not None and res.metrics.rows:
             print(res.metrics.table())
-        if res.pipeline is not None:
-            print(res.miss_report().table())
+        print(res.miss_report().table())
         if res.trace is not None:
             path = res.trace.export(args.trace)
             n_ev = len(res.trace.events())

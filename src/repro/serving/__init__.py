@@ -10,23 +10,24 @@ Layers, ingress to silicon:
   ingress, per-app policies), and closed-loop clients (bounded in-flight
   frames, jittered retry-on-shed) as an alternative to open-loop arrivals.
 * ``events``    — priority-queue discrete-event core with real tail-batch
-  deadline semantics; reference implementation, supports real executors;
-  its per-machine ``MachineCore`` is the composable stage brick.
-* ``replay``    — numpy-vectorized per-machine replay kernel (the hot path),
-  property-tested against the event core.
-* ``engine``    — DAG-level adapter executing a Harpagon ``Plan`` over a
-  frame stream (fanout expansion, per-module dispatch, e2e accounting).
+  deadline semantics; the single-module reference oracle; its per-machine
+  ``MachineCore`` is the composable stage brick.
+* ``replay``    — numpy-vectorized per-machine replay kernel (the pipelined
+  loop's segment fast path), property-tested against the event core.
+* ``engine``    — ``ServingEngine.run`` executes a Harpagon ``Plan`` over a
+  frame stream through the pipelined co-simulation (the one served path).
 * ``pipeline``  — multi-module pipelined co-simulation: frames traverse the
   DAG as tracked entities, downstream ingress fed by upstream batch
   completions, bounded queues exert backpressure, per-frame fanout can be
   stochastic and sibling-correlated, clients/admission live inside the
-  event loop.  Selected via ``ServingEngine.run(pipeline=True)``.
-* ``control``   — the incremental control plane (pipeline mode only):
-  windowed trend-forecast rate estimation, warm-start ``Planner.replan``
-  at every epoch, and hot-swap of the resulting ``PlanDelta`` onto the
-  live stages without dropping in-flight frames.  Selected via
-  ``ServingEngine.run(pipeline=True, control=ControlLoopConfig(...))``;
-  the per-epoch audit trail is returned as ``ServeResult.epochs``.
+  event loop.  ``ServingEngine.run(pipeline=PipelineConfig(...))`` bounds
+  queues, draws fanout, or selects the ``reference=True`` oracle loop.
+* ``control``   — the incremental control plane: windowed trend-forecast
+  rate estimation, warm-start ``Planner.replan`` at every epoch, and
+  hot-swap of the resulting ``PlanDelta`` onto the live stages without
+  dropping in-flight frames.  Selected via
+  ``ServingEngine.run(control=ControlLoopConfig(...))``; the per-epoch
+  audit trail is returned as ``ServeResult.epochs``.
 * ``service_time`` — pluggable batch service durations: ``analytic``
   (profiled constant, bit-exact default), ``trace`` (recorded samples,
   deterministic replay), ``live`` (real executors timed per batch).
@@ -44,9 +45,9 @@ Layers, ingress to silicon:
   pipelined loop, with watchdog-based detection (suspect → dead on missed
   batch heartbeats), frame-conserving re-queue recovery, out-of-band
   failure replans with warm-spare promotion, and allocator repacks on
-  shared-device death.  Selected via ``ServingEngine.run(pipeline=True,
-  faults=FaultConfig(...))``; disabled ⇒ bit-exact with the fault-free
-  engine.
+  shared-device death.  Selected via
+  ``ServingEngine.run(faults=FaultConfig(...))``; disabled ⇒ bit-exact
+  with the fault-free engine.
 * ``tenancy``   — the multi-tenant shared pool: a device-centric plan view
   (`DevicePlan`), a global allocator FFD-packing fractional module residues
   onto shared devices under an interference-aware e2e-SLO guard, and

@@ -9,8 +9,9 @@ core simulates is *batch formation and service* with real deadline semantics:
   finite ``timeout``, when the opener has waited ``timeout`` seconds (partial
   flush, exactly what a real frontend does because it cannot know whether
   more requests are coming);
-* closed batches queue FIFO at the machine; service takes the profiled
-  duration (or a real measured executor call) and the machine frees.
+* closed batches queue FIFO at the machine; service takes the machine's
+  duration (the profiled constant, or a service-time source's draw in the
+  pipelined loop) and the machine frees.
 
 The per-machine mechanics live in :class:`MachineCore` — a composable stage
 brick with no event loop of its own.  Two owners drive it: the single-module
@@ -20,8 +21,8 @@ co-simulation (`repro.serving.pipeline`), where many cores across DAG stages
 share one global event loop and upstream batch completions feed downstream
 formation buffers.
 
-This is the *reference* implementation: it supports real executors and
-arbitrary arrival patterns, and the vectorized hot path
+`simulate_module_events` is the *reference* implementation: it handles
+arbitrary arrival patterns, and the vectorized kernel
 (`repro.serving.replay`) is property-tested to agree with it.  End-of-stream
 handling when ``timeout is None`` is governed by ``tail``:
 
@@ -187,9 +188,6 @@ def simulate_module_events(
     *,
     timeout: "float | None | Mapping[int, float]" = None,
     tail: str = "flush",
-    executor: Callable[[Machine, int], float] | None = None,
-    phantom: np.ndarray | None = None,
-    on_batch: "Callable[[Machine, float, float, list, float], None] | None" = None,
 ) -> tuple[np.ndarray, dict[int, int]]:
     """Simulate one module; returns ``(finish, batches_per_machine)``.
 
@@ -197,20 +195,8 @@ def simulate_module_events(
     when no upstream tail cascades are present); ``assignment[i]`` the
     machine id serving request ``i``.  ``timeout`` may be a single deadline
     or a per-machine-id mapping.  ``finish[i]`` is the absolute completion
-    time (``np.nan`` for dropped tail requests).  ``executor`` (when given)
-    is called at each batch start with ``(machine, group_size)`` and must
-    return the measured service duration in seconds.
-
-    ``phantom`` marks frontend dummy requests.  They occupy batch slots and
-    are executed with the batch (an executor sees the full batch size), but
-    a flush deadline is armed only when a *real* request lands in the
-    formation buffer, and a leftover buffer holding only phantoms is
-    discarded at end of stream instead of flushed.
-
-    ``on_batch`` (when given) is a passive observer called at every batch
-    start with ``(machine, start, end, members, batch_ready)`` — the
-    observability layer's per-batch feed (``batch_ready`` is when the batch
-    closed); it never influences the simulation.
+    time (``np.nan`` for dropped tail requests); service takes each
+    machine's profiled duration.
     """
     if tail not in ("flush", "drop"):
         raise ValueError(f"unknown tail policy {tail!r}")
@@ -220,7 +206,6 @@ def simulate_module_events(
         timeouts = {m.mid: timeout for m in machines}
     ready = np.asarray(ready, dtype=np.float64)
     n = ready.size
-    real = np.ones(n, dtype=bool) if phantom is None else ~np.asarray(phantom, bool)
     finish = np.full(n, np.nan)
     cores = {m.mid: MachineCore(m, timeouts[m.mid]) for m in machines}
     batches = {m.mid: 0 for m in machines}
@@ -228,34 +213,13 @@ def simulate_module_events(
 
     def start_next(mid: int, now: float) -> None:
         core = cores[mid]
-        m = core.machine
-        if on_batch is None:
-            dur = (
-                (lambda rids: executor(m, len(rids)))
-                if executor is not None
-                else (lambda rids: m.config.duration)
-            )
-        else:
-            drawn: list[float] = []
-            closed_at = core.queue[0][0] if core.queue else 0.0
-
-            def dur(rids, _d=drawn) -> float:
-                d = (
-                    executor(m, len(rids))
-                    if executor is not None
-                    else m.config.duration
-                )
-                _d.append(d)
-                return d
-
-        started = core.start(now, dur)
+        d = core.machine.config.duration
+        started = core.start(now, lambda rids: d)
         if started is None:
             return
         end, rids = started
         batches[mid] += 1
         finish[rids] = end
-        if on_batch is not None:
-            on_batch(m, end - drawn[0], end, rids, closed_at)
         heapq.heappush(heap, (end, _FREE, mid, 0))
 
     def close_batch(mid: int, batch_ready: float, now: float) -> None:
@@ -272,7 +236,7 @@ def simulate_module_events(
             ai += 1
             mid = int(assignment[rid])
             core = cores[mid]
-            deadline = core.add(rid, t, bool(real[rid]))
+            deadline = core.add(rid, t, True)
             if deadline is not None:
                 heapq.heappush(heap, (deadline, _FLUSH, mid, core.token))
             if core.full:
@@ -292,15 +256,12 @@ def simulate_module_events(
             tails_done = True
             for mid, core in cores.items():
                 buf = core.buf
-                has_real = any(real[r] for r in buf)
-                if buf and has_real and timeouts[mid] is None and tail == "flush":
-                    # flush at the last REAL member's arrival: the frontend
-                    # stops injecting phantoms once the stream ends, so
-                    # trailing phantoms must not inflate real tail latency
-                    # max over VALUES, not stream positions: under causal
-                    # order a backdated cascade member may sit after the
-                    # time-max one (identical for sorted streams)
-                    t_last = max(float(ready[r]) for r in buf if real[r])
+                if buf and timeouts[mid] is None and tail == "flush":
+                    # flush at the last member's arrival — max over VALUES,
+                    # not stream positions: under causal order a backdated
+                    # cascade member may sit after the time-max one
+                    # (identical for sorted streams)
+                    t_last = max(float(ready[r]) for r in buf)
                     close_batch(mid, batch_ready=t_last, now=t_last)
                 elif buf:
                     core.discard()  # drop (finish stays NaN)

@@ -13,7 +13,7 @@ FAULT_KINDS = ("crash", "straggler", "device_loss")
 
 @dataclass(frozen=True)
 class FaultConfig:
-    """Knobs for ``ServingEngine.run(..., faults=...)`` (pipeline mode only).
+    """Knobs for ``ServingEngine.run(..., faults=...)``.
 
     ``mtbf`` arms an exponential fault process (mean seconds between
     faults, first draw at ``start``); ``schedule`` lists explicit

@@ -16,8 +16,8 @@ seed numbers exactly):
   shedding at ingress (per-app policies supported) bounds p99 under bursty
   overload at the price of an explicit, reported shed rate.
 * **closed-loop clients** (`.clients`) — bounded in-flight frames per
-  client with optional jittered retry-on-shed, run to a fixed point with
-  the engine's simulated per-frame latencies.
+  client with optional jittered retry-on-shed, issued by the pipelined
+  event loop as frames actually resolve.
 
 Under the incremental control plane (``repro.serving.control``) the
 frontend re-reads *per-epoch plan state* instead of run constants: an
@@ -49,8 +49,8 @@ from .admission import (
     TokenBucket,
     make_admission,
 )
-from .clients import ClosedLoopClients, closed_loop_ingress
-from .dummy import merge_phantoms, phantom_times
+from .clients import ClosedLoopClients
+from .dummy import phantom_times
 
 
 @dataclass(frozen=True)
@@ -82,8 +82,6 @@ __all__ = [
     "FrontendConfig",
     "QueueDepth",
     "TokenBucket",
-    "closed_loop_ingress",
     "make_admission",
-    "merge_phantoms",
     "phantom_times",
 ]

@@ -12,21 +12,18 @@ Policies (resolved per app via :func:`make_admission`):
 * :class:`TokenBucket`       — sustained ``rate`` frames/s with ``burst``
   bucket depth; admitted traffic over any window ``[t, t+w]`` is bounded by
   ``rate * w + burst``.
-* :class:`QueueDepth`        — shed when a virtual ingress queue, draining at
-  the provisioned frame rate, already holds ``depth`` frames (the classic
-  bounded-buffer frontend).
+* :class:`QueueDepth`        — shed when the source stages already hold
+  ``depth`` instances waiting to start service (the classic bounded-buffer
+  frontend, against the live backlog of the pipelined loop).
 
-Controllers are *stateful sequential* objects: `admit(t)` must be called in
-non-decreasing time order (the engine feeds it the sorted arrival stream;
-the closed-loop client simulation feeds it its own monotone event clock).
+Controllers are *stateful sequential* objects: `admit_live(t, backlog)` must
+be called in non-decreasing time order (the pipelined loop calls it at each
+issue instant of its event clock).
 """
 from __future__ import annotations
 
-from collections import deque
 from dataclasses import dataclass
 from typing import Mapping, Union
-
-import numpy as np
 
 
 @dataclass(frozen=True)
@@ -43,14 +40,10 @@ class TokenBucket:
 
 @dataclass(frozen=True)
 class QueueDepth:
-    """Bounded virtual ingress queue: shed when ``depth`` frames are waiting.
-
-    The virtual queue drains FIFO at ``drain_rate`` (``None`` = provisioned
-    frame rate), approximating the pipeline's first-stage service capacity.
-    """
+    """Bounded ingress queue: shed when ``depth`` instances are waiting at
+    the source stages (formation + queued + parked)."""
 
     depth: int = 16
-    drain_rate: float | None = None
 
 
 AdmissionPolicy = Union[None, str, TokenBucket, QueueDepth]
@@ -69,17 +62,13 @@ class AdmissionController:
             if self._rate <= 0 or policy.burst < 1.0:
                 raise ValueError("token bucket needs rate>0 and burst>=1")
         elif isinstance(policy, QueueDepth):
-            self._drain = (
-                policy.drain_rate if policy.drain_rate is not None else frame_rate
-            )
-            if self._drain <= 0 or policy.depth < 1:
-                raise ValueError("queue-depth needs drain_rate>0 and depth>=1")
+            if policy.depth < 1:
+                raise ValueError("queue-depth needs depth>=1")
         else:
             raise TypeError(f"unknown admission policy {policy!r}")
-        # passive telemetry sink (`observability.Observability`): both
-        # engine paths wire it — the flat path before shed_stream, the
-        # pipelined loop before run_pipeline — so every admission denial
-        # lands in the trace/metrics at decision resolution.  Closed-loop
+        # passive telemetry sink (`observability.Observability`): the engine
+        # wires it before run_pipeline, so every admission denial lands in
+        # the trace/metrics at decision resolution.  Closed-loop
         # interim denials the client will re-issue carry the distinct
         # "shed_retry" cause, so summing "shed" instants always equals
         # terminal sheds; the pipelined loop's terminal shed emit defers
@@ -91,67 +80,49 @@ class AdmissionController:
     def rebind(self, frame_rate: float) -> None:
         """Re-read the provisioned frame rate (control-plane plan hot-swap).
 
-        Policies whose ``rate`` / ``drain_rate`` is ``None`` are bound to
-        the *provisioned* rate; under an epoch-based control loop that rate
-        is per-epoch plan state, not a run constant.  Rebinding preserves
-        the live bucket level / virtual queue — only the refill / drain
-        pace follows the new plan.  Explicit numeric policies are pinned by
-        the operator and do not move.
+        A token bucket whose ``rate`` is ``None`` is bound to the
+        *provisioned* rate; under an epoch-based control loop that rate is
+        per-epoch plan state, not a run constant.  Rebinding preserves the
+        live bucket level — only the refill pace follows the new plan.
+        Explicit numeric rates are pinned by the operator and do not move.
         """
         if frame_rate <= 0:
             raise ValueError("frame_rate must be positive")
         self.frame_rate = frame_rate
-        if isinstance(self.policy, TokenBucket):
-            if self.policy.rate is None:
-                self._rate = frame_rate
-        elif self.policy.drain_rate is None:
-            self._drain = frame_rate
+        if isinstance(self.policy, TokenBucket) and self.policy.rate is None:
+            self._rate = frame_rate
 
     def reset(self) -> None:
-        """Restore initial state (full bucket / empty queue)."""
+        """Restore initial state (full bucket, zero tallies)."""
         self.admitted = 0
         self.shed = 0
         if isinstance(self.policy, TokenBucket):
             self._tokens = float(self.policy.burst)
             self._last: float | None = None
-        else:
-            self._finish: deque[float] = deque()
-            self._free = 0.0
 
     def admit(self, t: float, cause: str = "shed") -> bool:
-        """Admit or shed one frame arriving at time ``t`` (non-decreasing).
+        """Token-bucket decision for one frame arriving at time ``t``
+        (non-decreasing).
 
         ``cause`` labels the observer instant emitted on denial — callers
         that will re-issue a denied frame pass a non-terminal cause.
         """
-        if isinstance(self.policy, TokenBucket):
-            if self._last is not None:
-                self._tokens = min(
-                    float(self.policy.burst),
-                    self._tokens + (t - self._last) * self._rate,
-                )
-            self._last = t
-            if self._tokens >= 1.0 - 1e-12:
-                self._tokens -= 1.0
-                self.admitted += 1
-                return True
-            self.shed += 1
-            if self.obs is not None:
-                self.obs.shed(t, cause)
-            return False
-        # queue depth: retire virtually-served frames, then check occupancy
-        q = self._finish
-        while q and q[0] <= t + 1e-12:
-            q.popleft()
-        if len(q) >= self.policy.depth:
-            self.shed += 1
-            if self.obs is not None:
-                self.obs.shed(t, cause)
-            return False
-        self._free = max(self._free, t) + 1.0 / self._drain
-        q.append(self._free)
-        self.admitted += 1
-        return True
+        if not isinstance(self.policy, TokenBucket):
+            raise TypeError("admit() is the token bucket's; use admit_live()")
+        if self._last is not None:
+            self._tokens = min(
+                float(self.policy.burst),
+                self._tokens + (t - self._last) * self._rate,
+            )
+        self._last = t
+        if self._tokens >= 1.0 - 1e-12:
+            self._tokens -= 1.0
+            self.admitted += 1
+            return True
+        self.shed += 1
+        if self.obs is not None:
+            self.obs.shed(t, cause)
+        return False
 
     def admit_live(self, t: float, backlog: int, cause: str = "shed") -> bool:
         """Admit or shed against *live* pipeline state (event-interleaved).
@@ -160,13 +131,9 @@ class AdmissionController:
         the pipelined engine passes the number of source-stage *instances*
         waiting to start service (formation + queued + parked; equal to
         frames whenever the source fanout is 1, as in every seed app).  A
-        :class:`TokenBucket` is purely time-based and behaves exactly like
+        :class:`TokenBucket` is purely time-based and delegates to
         :meth:`admit`; a :class:`QueueDepth` policy compares this real
-        occupancy against ``depth`` instead of its virtual drain-rate queue
-        — the whole point of the pipelined co-simulation is that shedding
-        reacts to actual instantaneous backlog rather than a modeled one
-        (so the same ``depth`` is a *different*, more honest threshold than
-        in the flat path's virtual queue).
+        occupancy against ``depth``.
         """
         if isinstance(self.policy, TokenBucket):
             return self.admit(t, cause)
@@ -177,12 +144,6 @@ class AdmissionController:
             return False
         self.admitted += 1
         return True
-
-    def shed_stream(self, arrivals: np.ndarray) -> np.ndarray:
-        """Vector form: boolean shed mask for a sorted arrival-time array."""
-        return np.fromiter(
-            (not self.admit(float(t)) for t in arrivals), dtype=bool, count=arrivals.size
-        )
 
 
 def make_admission(

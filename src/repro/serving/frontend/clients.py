@@ -1,48 +1,26 @@
 """Closed-loop clients: arrivals driven by completions, not by a clock.
 
-The PR-1 arrival processes are all *open-loop*: the stream keeps coming no
-matter how slow the service is, which is the right model for camera feeds
-but the wrong one for interactive clients.  A closed-loop client holds at
-most ``max_in_flight`` frames outstanding and issues the next one only after
-a completion (plus think time), so offered load self-throttles under
-overload — the classic closed-vs-open distinction in serving benchmarks.
+Open-loop arrival processes keep coming no matter how slow the service is,
+which is the right model for camera feeds but the wrong one for interactive
+clients.  A closed-loop client holds at most ``max_in_flight`` frames
+outstanding and issues the next one only after a completion (plus think
+time), so offered load self-throttles under overload — the classic
+closed-vs-open distinction in serving benchmarks.
 
-The ingress simulation here is a sequential event walk over client slots:
-each slot issues a frame, the admission controller (if any) admits or sheds
-it at the issue instant, an admitted frame completes after the per-frame
-latency given by the ``latency`` oracle, and the slot frees ``think`` later.
-A shed frame is retried with exponentially-jittered backoff (when enabled)
-until ``max_retries`` is exhausted, then the frame is terminal.  The bound
-exists so a dead or unrecovered stage can't spin the shed→retry loop
-forever: every frame leaves the system in bounded attempts.  Terminal
-classification differs by path — the pipelined co-simulation records an
-exhausted frame as ``dropped`` with a ``retry_exhausted`` trace cause
-(distinct from a first-sight terminal ``shed``), while this deprecated flat
-path folds it into ``shed``.
-
-The oracle makes this a *fixed-point* formulation: the engine seeds it with
-the plan's modeled end-to-end latency, replays the DAG on the generated
-arrivals, feeds the simulated per-frame latencies back in, and iterates
-until the arrival times stop moving (`ServingEngine._run_closed_loop`).
-
-.. deprecated::
-    The fixed-point path is superseded by the event-interleaved client loop
-    of the pipelined co-simulation (``ServingEngine.run(pipeline=True)``),
-    where slots react to *actual* completions instead of a previous pass's
-    latency oracle.  `closed_loop_ingress` and the engine shim remain for
-    the flat path (`ServingEngine.run` warns ``DeprecationWarning``), and
-    both formulations are pinned to agree within tolerance on uniform
-    arrivals (tests/test_pipeline.py).  The `ClosedLoopClients` dataclass
-    itself is *not* deprecated — the pipeline reuses it as its client spec.
+The pipelined co-simulation (`repro.serving.pipeline.core.run_pipeline`)
+runs the client slots inside its event loop: a slot issues a frame, the
+admission controller (if any) admits or sheds it at the issue instant
+against live backlog, and the slot issues again ``think`` after the frame
+actually resolves.  A shed frame is retried with exponentially-jittered
+backoff (when enabled) until ``max_retries`` is exhausted; an exhausted
+frame is recorded as ``dropped`` with a ``retry_exhausted`` trace cause
+(distinct from a first-sight terminal ``shed``).  The bound exists so a
+dead or unrecovered stage can't spin the shed→retry loop forever: every
+frame leaves the system in bounded attempts.
 """
 from __future__ import annotations
 
-import heapq
 from dataclasses import dataclass
-
-import numpy as np
-
-from .admission import AdmissionController
 
 
 @dataclass(frozen=True)
@@ -63,11 +41,9 @@ class ClosedLoopClients:
     backoff: "float | None" = 0.05
     #   base retry backoff, doubled per attempt, jittered.  ``None`` = re-read
     #   the LIVE plan's modeled end-to-end latency at every retry (per-epoch
-    #   state under a control loop, the fixed-point oracle otherwise): a shed
-    #   client waits about one service round of the plan that is actually
-    #   serving, not a run-constant guess
-    max_iters: int = 5        # engine fixed-point iterations
-    tol: float = 1e-3         # arrival-time convergence tolerance (seconds)
+    #   state under a control loop, the plan's otherwise): a shed client
+    #   waits about one service round of the plan that is actually serving,
+    #   not a run-constant guess
 
     def __post_init__(self):
         if self.n_clients < 1 or self.max_in_flight < 1:
@@ -76,82 +52,3 @@ class ClosedLoopClients:
             raise ValueError(f"unknown think_dist {self.think_dist!r}")
         if self.backoff is not None and self.backoff < 0.0:
             raise ValueError("backoff must be >= 0 (or None for live latency)")
-
-
-def closed_loop_ingress(
-    cfg: ClosedLoopClients,
-    n_frames: int,
-    frame_rate: float,
-    latency: np.ndarray,
-    *,
-    admission: AdmissionController | None = None,
-    seed: int = 0,
-) -> tuple[np.ndarray, np.ndarray, int]:
-    """Simulate the client/admission ingress; returns ``(issue, shed, attempts)``.
-
-    ``latency[i]`` is the oracle end-to-end latency of frame ``i`` (frames
-    are numbered in issue order).  ``issue[i]`` is the admitted arrival time
-    of frame ``i`` (its final attempt time when permanently shed),
-    ``shed[i]`` marks frames rejected at ingress for good, and ``attempts``
-    counts every issue attempt including retries.  ``frame_rate`` only
-    staggers the initial slot starts (one provisioned inter-frame gap apart).
-    """
-    if latency.shape != (n_frames,):
-        raise ValueError("latency oracle must have one entry per frame")
-    rng = np.random.default_rng(seed)
-    slots = cfg.n_clients * cfg.max_in_flight
-    issue = np.zeros(n_frames)
-    shed = np.zeros(n_frames, dtype=bool)
-    attempts = 0
-    next_frame = 0
-
-    def think() -> float:
-        if cfg.think_time <= 0.0:
-            return 0.0
-        if cfg.think_dist == "const":
-            return cfg.think_time
-        return float(rng.exponential(cfg.think_time))
-
-    # heap of (time, seq, frame, tries); frame == -1 means "slot wants a new
-    # frame".  seq keeps heap comparisons away from ties.
-    seq = 0
-    heap: list[tuple[float, int, int, int]] = []
-    for k in range(min(slots, n_frames)):
-        heapq.heappush(heap, (k / frame_rate, seq, -1, 0))
-        seq += 1
-
-    while heap:
-        t, _, frame, tries = heapq.heappop(heap)
-        if frame == -1:
-            if next_frame >= n_frames:
-                continue  # stream exhausted: slot retires
-            frame = next_frame
-            next_frame += 1
-            tries = 0
-        attempts += 1
-        will_retry = cfg.retry_on_shed and tries < cfg.max_retries
-        admitted = (
-            admission.admit(t, "shed_retry" if will_retry else "shed")
-            if admission is not None
-            else True
-        )
-        if admitted:
-            issue[frame] = t
-            done = t + max(float(latency[frame]), 0.0)
-            heapq.heappush(heap, (done + think(), seq, -1, 0))
-        elif cfg.retry_on_shed and tries < cfg.max_retries:
-            # backoff=None: wait about one modeled service round (the oracle
-            # latency is this path's "live plan state")
-            base = (
-                cfg.backoff
-                if cfg.backoff is not None
-                else max(float(latency[frame]), 1e-3)
-            )
-            delay = base * (2.0 ** tries) * float(rng.uniform(0.5, 1.5))
-            heapq.heappush(heap, (t + delay, seq, frame, tries + 1))
-        else:
-            issue[frame] = t
-            shed[frame] = True
-            heapq.heappush(heap, (t + think(), seq, -1, 0))
-        seq += 1
-    return issue, shed, attempts

@@ -45,22 +45,3 @@ def phantom_times(ready: np.ndarray, target_rate: float) -> np.ndarray:
     if k <= 0:
         return np.zeros(0)
     return t0 + (np.arange(k, dtype=np.float64) + 0.5) / pad
-
-
-def merge_phantoms(
-    ready: np.ndarray, phantoms: np.ndarray
-) -> tuple[np.ndarray, np.ndarray]:
-    """Merge a sorted real stream with phantom times.
-
-    Returns ``(merged_ready, phantom_mask)`` with the merge stable (real
-    requests win ties, and the real sub-stream keeps its original order, so
-    real results can be sliced back out with the mask).
-    """
-    if phantoms.size == 0:
-        return ready, np.zeros(ready.size, dtype=bool)
-    merged = np.concatenate([ready, phantoms])
-    mask = np.concatenate(
-        [np.zeros(ready.size, dtype=bool), np.ones(phantoms.size, dtype=bool)]
-    )
-    order = np.argsort(merged, kind="stable")
-    return merged[order], mask[order]

@@ -26,8 +26,8 @@ Fields of a module's metrics row beside the batch counts (all sums, so rows
 add up): ``collect_s`` — Σ over the real members of its started batches of
 (batch close − member ready); ``queue_s`` — Σ (batch start − batch close);
 ``service_s`` — Σ (batch end − batch start); ``waited`` — those members.
-All four are in the loop's clock, on every path (event loop, segment fast
-path, flat engine).  With real executors (``launch/serve.py --real``) a row
+All four are in the loop's clock, on both paths (event loop, segment fast
+path).  With real executors (``launch/serve.py --real``) a row
 also carries ``dispatch_s`` / ``dispatch_n`` / ``dispatch_max_s`` (host
 time enqueueing the compiled forward; ``dispatch_n`` is the call count),
 and the same three for ``sync`` (the wait in ``block_until_ready``).  The
@@ -38,7 +38,7 @@ collections while the run was in progress.
 The spans themselves land in a profiler trace taken around a real run, e.g.
 ``jax.profiler.start_trace(dir)`` before and ``stop_trace()`` after
 ``repro.launch.serve.main(["--arch", "smollm-360m", "--real",
-"--pipeline", "--trace"])``, and show on the trace's host plane next to the
+"--trace"])``, and show on the trace's host plane next to the
 device's ops (TensorBoard's profile plugin or Perfetto read the
 ``.xplane.pb``).  Each module's forward is jitted under its own name and
 ``jax.named_scope``, so the device's XLA Modules line names it.
@@ -233,7 +233,7 @@ class Observability:
                 delta=record.delta_summary,
             )
 
-    # -- column-level hooks (segment fast path / flat engine) ---------------
+    # -- column-level hooks (segment fast path) ------------------------------
     def bulk_module(self, module: str, *, batches: int, members: int,
                     phantoms: int, slots: int, busy: float) -> None:
         if self.metrics is not None:

@@ -42,8 +42,8 @@ assert:  ``sum(counts.values()) == misses == offered − completed-in-SLO``.
 
 The columns feeding the middle rows (``stalled`` / ``flushed`` / ``fan``)
 are always-on and cheap (one boolean/int write at an event that already
-touches the frame), so forensics needs no opt-in: every ``pipeline=True``
-result can answer ``miss_report()``.  One honest limitation: the segment
+touches the frame), so forensics needs no opt-in: every served result can
+answer ``miss_report()``.  One honest limitation: the segment
 fast path never deadline-flushes (it only runs when the whole stream is
 quiescent), so ``flushed`` stays ``False`` there and a would-be
 ``flush_waste`` frame classifies as ``service_overrun`` — conservation is

@@ -151,7 +151,7 @@ class MetricsRegistry:
             if seconds > tot[2]:
                 tot[2] = seconds
 
-    # -- column-level accumulation (segment fast path / flat engine) --------
+    # -- column-level accumulation (segment fast path) -----------------------
     def bulk(self, module: str, *, batches: int, members: int,
              phantoms: int, slots: int, busy: float) -> None:
         """Fold one vectorized module replay's aggregate into the epoch."""

@@ -1,8 +1,8 @@
 """Multi-module pipelined co-simulation: one event loop over the whole DAG.
 
-The flat engine (`repro.serving.engine._serve`) replays modules one at a
-time in topological order: each module's full request stream is known before
-the module runs, and downstream only sees per-frame *finish times*.  That is
+A per-module replay (`repro.serving.replay`) runs modules one at a time in
+topological order: each module's full request stream is known before the
+module runs, and downstream only sees per-frame *finish times*.  That is
 exact while queues are unbounded and fanout is deterministic — and blind to
 everything else.  This core instead pushes each frame through the app DAG as
 a tracked entity inside one global discrete-event loop:
@@ -15,8 +15,8 @@ a tracked entity inside one global discrete-event loop:
   until the stage drains — upstream throughput degrades exactly like a real
   pipeline with finite inter-stage buffers;
 * **fanout is per-frame** (`.fanout.FanoutSpec`): deterministic accumulator
-  (flat-engine-identical) or seeded stochastic draws correlated across
-  sibling modules;
+  (the replay kernel's `expand_fanout`) or seeded stochastic draws
+  correlated across sibling modules;
 * **clients and admission live inside the loop**: closed-loop slots issue
   the next frame when the previous one actually resolves, and queue-depth
   admission sheds against the true number of frames in flight — no
@@ -27,9 +27,9 @@ arrivals join batches at their deadline instant, and upstream machine-frees
 deliver before a downstream flush at the same instant fires (see
 `stages._K_*`).  All same-time machine-frees are collected before their
 outputs are delivered, sorted by ``(stage topo index, frame id)`` — the same
-order the flat engine's stable ready-sort produces, which is what makes the
-co-simulation cross-validate bit-for-bit against the vectorized kernel on
-unbounded queues with deterministic fanout.
+order a stable ready-sort produces, which is what makes the co-simulation
+cross-validate bit-for-bit against the vectorized kernel on unbounded queues
+with deterministic fanout.
 
 **Macro-event hot path.**  The event-by-event loop is the semantics oracle,
 not the speed target.  Three layers sit on top of it:
@@ -43,16 +43,18 @@ not the speed target.  Three layers sit on top of it:
 * when the run is **quiescent of everything only the event loop can
   express** — open-loop issue, unbounded queues, deterministic fanout, no
   phantom streaming, no admission, no control epochs — the entire segment
-  (here: the whole run) is delegated to the vectorized flat kernel
+  (here: the whole run) is delegated to the vectorized replay kernel
   (`.fastpath`), a cache of the PR-3 equivalence theorem.  The event loop
   would be re-entered at the segment boundary; with run-constant
   eligibility there is exactly one segment.
 
 ``PipelineConfig(reference=True)`` pins the original event-by-event loop
-(global heapq, scalar delivery, no fast path) as the bit-exactness oracle.
+(scalar delivery, no fast path) as the bit-exactness oracle.  Both loops
+pop one global ``heapq`` in ``(t, kind, seq)`` order.
 """
 from __future__ import annotations
 
+import heapq
 import math
 from dataclasses import dataclass
 from typing import Mapping
@@ -62,7 +64,6 @@ import numpy as np
 from ...core.dag import AppDAG
 from ..frontend.admission import AdmissionController
 from ..frontend.clients import ClosedLoopClients
-from .equeue import make_queue
 from .fanout import FanoutSpec
 from .result import FrameTable, PipelineResult
 from .stages import (
@@ -74,37 +75,26 @@ from .stages import (
 class PipelineConfig:
     """Engine-facing knobs for ``ServingEngine.run(pipeline=...)``.
 
-    ``queue_cap`` bounds every stage's ingress backlog (instances waiting to
-    start service); ``None`` disables backpressure and reproduces the flat
-    engine's unbounded-queue numbers.  ``fanout`` selects deterministic or
-    correlated-stochastic per-frame fanout.
+    ``queue_cap`` bounds every stage's ingress backlog of real instances
+    waiting to start service; ``None`` disables backpressure (unbounded
+    queues).  ``fanout`` selects deterministic or correlated-stochastic
+    per-frame fanout.
 
-    Performance knobs (results are invariant to all of them):
+    Performance knobs (results are invariant to both):
 
-    * ``reference`` — run the original event-by-event loop (global heapq,
-      scalar per-instance delivery, no segment fast-path): the bit-exactness
+    * ``reference`` — run the original event-by-event loop (scalar
+      per-instance delivery, no segment fast-path): the bit-exactness
       oracle the macro-event path is property-tested against.
     * ``fast_path`` — allow delegating a control-quiescent run to the
-      vectorized flat kernel (`repro.serving.pipeline.fastpath`); setting it
-      ``False`` keeps the macro-event general loop even when eligible
+      vectorized replay kernel (`repro.serving.pipeline.fastpath`); setting
+      it ``False`` keeps the macro-event general loop even when eligible
       (useful for benchmarking the loop itself).
-    * ``event_queue`` — ``"heap"`` (single global heap, default) or
-      ``"calendar"`` (bucketed calendar queue); both serve the identical
-      ``(t, kind, seq)`` order.  ``quantum`` overrides the calendar bucket
-      width (default: mean issue spacing).  The calendar's O(1)-amortized
-      promise does not survive CPython at this event population: the
-      C-implemented global heap measures ~10-40% faster than pure-Python
-      bucket bookkeeping across quantum settings (see the README speedup
-      table), so the heap stays the default and the calendar remains the
-      selectable, equivalence-pinned alternative.
     """
 
     fanout: FanoutSpec = FanoutSpec()
     queue_cap: "int | None" = None
     reference: bool = False
     fast_path: bool = True
-    event_queue: str = "heap"
-    quantum: "float | None" = None
 
 
 def run_pipeline(
@@ -122,8 +112,6 @@ def run_pipeline(
     e2e_hint: float = 0.05,
     reference: bool = False,
     fast_path: bool = True,
-    event_queue: str = "heap",
-    quantum: "float | None" = None,
     obs=None,
     faults=None,
 ) -> PipelineResult:
@@ -179,7 +167,7 @@ def run_pipeline(
 
         if fastpath.eligible(dag, stages):
             # the whole run is one quiescent segment: delegate to the
-            # vectorized flat kernel (the PR-3 equivalence theorem, cached;
+            # vectorized replay kernel (the PR-3 equivalence theorem, cached;
             # streams run in the event loop's causal order, backdated
             # end-of-stream tails included — see fastpath docstring)
             return fastpath.run_flat_segment(
@@ -240,23 +228,37 @@ def run_pipeline(
     def stage_stream_done(m: str) -> bool:
         return acc_count[m] >= n_frames and pend_total[m] == 0
 
-    if quantum is None and event_queue == "calendar" and not reference:
-        # default calendar bucket = mean issue spacing (events cluster at
-        # the arrival timescale); correctness is quantum-invariant.  The
-        # heap queue never reads it, so skip the O(n) scan there.
-        if issue is not None and n_frames > 1:
-            span = float(np.max(issue)) - float(np.min(issue))
-            quantum = max(span / n_frames, 1e-9)
-        else:
-            quantum = max(e2e_hint / 8.0, 1e-9)
-    heap = make_queue("heap" if reference else event_queue, quantum)
-    heap_push = heap.push
+    # one global heap of (t, kind, seq, stage, payload): ``seq`` is the
+    # global push counter, so ties at one instant resolve in push order and
+    # no comparison ever reaches the payload
+    heap: list = []
+    heappush, heappop = heapq.heappush, heapq.heappop
     _seq = 0
+    # heap entries that cannot move a real frame: phantom-chain ticks and
+    # the frees of phantom-only batches.  The loop never runs on these
+    # alone (see the phantom handler): a stage waiting on the quiescence
+    # flush must not be starved by phantom traffic elsewhere
+    n_idle = 0
 
     def push(t: float, kind: int, stage: "str | None", payload) -> None:
-        nonlocal _seq
-        heap_push((t, kind, _seq, stage, payload))
+        nonlocal _seq, n_idle
+        if kind == _K_ARRIVE:
+            if payload[0] == "phantom":
+                n_idle += 1
+        elif kind == _K_FREE and not payload[1]:
+            n_idle += 1
+        heappush(heap, (t, kind, _seq, stage, payload))
         _seq += 1
+
+    def pop() -> tuple:
+        nonlocal n_idle
+        e = heappop(heap)
+        if e[1] == _K_ARRIVE:
+            if e[4][0] == "phantom":
+                n_idle -= 1
+        elif e[1] == _K_FREE and not e[4][1]:
+            n_idle -= 1
+        return e
 
     # upstream machines held busy by undelivered outputs: (stage, mid) -> count
     blocked: dict[tuple[str, int], int] = {}
@@ -369,7 +371,7 @@ def run_pipeline(
 
     def deliver_entries(entries, now) -> None:
         """Deliver newly-available frames, frame-ordered within each stage —
-        the order the flat engine's stable ready-sort would produce."""
+        the order a stable ready-sort would produce."""
         for c, f, t, blocker in sorted(
             entries, key=lambda e: (torder[e[0]], e[1])
         ):
@@ -658,22 +660,22 @@ def run_pipeline(
     t_now = 0.0
     while True:
         if not heap:
-            # stream quiescent: resolve leftover partial batches (the flat
-            # core does this once at end of stream; interleaved clients can
+            # stream quiescent: resolve leftover partial batches (the
+            # single-module core does this once at end of stream; clients can
             # also quiesce mid-run when every slot waits on a stuck frame —
             # flushing is then the only causally-consistent way forward).
             # Per round, flush every stage whose ANCESTORS hold no more
             # real work: an upstream tail flush can still deliver members
             # that complete a downstream batch, so a stage must not flush
-            # until everything above it has fully drained (the flat engine
-            # replays whole modules in topo order for exactly this reason).
+            # until everything above it has fully drained (the replay kernel
+            # runs whole modules in topo order for exactly this reason).
             # Sibling stages, however, must flush in the SAME round: their
             # tail completions re-enter the heap and process in global time
             # order, so a shared child receives them in availability order
             # — flushing one sibling per round delivered a later-flushed
             # sibling's EARLIER completion after an earlier-flushed
             # sibling's later one, silently reordering the child's dispatch
-            # stream relative to the flat engine's stable ready-sort.
+            # stream relative to the replay kernel's stable ready-sort.
             acted = False
             # frozen per round: a child must not flush in the round its
             # ancestor's tail closed — that tail's completion still has to
@@ -718,7 +720,7 @@ def run_pipeline(
                     push(t_now + relax_every, _K_EPOCH, None, ("relax",))
                     relax_armed = True
             continue
-        t, kind, _s, stage_name, payload = heap.pop()
+        t, kind, _s, stage_name, payload = pop()
         t_now = max(t_now, t)
         if kind == _K_ARRIVE:
             what = payload[0]
@@ -737,9 +739,18 @@ def run_pipeline(
                     continue  # a hot-swap re-anchored the streamer: stale chain
                 if stage_stream_done(m):
                     continue  # this stage's real stream is over: chain dies
+                if n_idle == len(heap):
+                    # nothing but phantom traffic is left to happen: padding
+                    # now would only fill batches that no real event will
+                    # ever come to serve (or hold a timeout-less tail past
+                    # its last real arrival).  Go dormant, so the loop
+                    # reaches the quiescence flush; the next delivery or
+                    # free revives the chain
+                    st.phantom_paused = True
+                    continue
                 period = 1.0 / st.phantom_target
                 if st.delivered == 0:
-                    # pad only from the first real arrival onward (the flat
+                    # pad only from the first real arrival onward (the
                     # injector spans the real stream): go dormant rather
                     # than warm an idle stage — or keep the heap alive while
                     # an upstream wedge waits for the quiescence flush; the
@@ -752,8 +763,8 @@ def run_pipeline(
                 due = st.anchor + (st.delivered + 1.5) * period
                 if t >= due - 1e-12:
                     # collection fell behind target * elapsed: pad with one
-                    # phantom (the flat injector's deficit-padding expressed
-                    # causally), then resync the anchor so the stage is
+                    # phantom (`frontend.dummy.phantom_times`' deficit
+                    # padding expressed causally), then resync the anchor so the stage is
                     # considered paid-up through now — old deficit is
                     # forgiven rather than burst-injected, and total
                     # arrivals stay rate-limited at the target.  A stage
@@ -783,11 +794,9 @@ def run_pipeline(
             # collect every machine-free at this instant before delivering,
             # so cross-machine outputs land downstream in frame order
             frees = [(stage_name, payload[0])]
-            nxt = heap.peek()
-            while nxt is not None and nxt[0] == t and nxt[1] == _K_FREE:
-                heap.pop()
+            while heap and heap[0][0] == t and heap[0][1] == _K_FREE:
+                nxt = pop()
                 frees.append((nxt[3], nxt[4][0]))
-                nxt = heap.peek()
             if faults is not None:
                 # fence dead machines: a fenced core's "completion" never
                 # happened (its members are re-queued at the failure

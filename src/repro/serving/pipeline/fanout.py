@@ -1,6 +1,6 @@
 """Per-frame fanout realization: deterministic accumulator or correlated draws.
 
-The flat engine scales every module's instance stream by the fixed ratio
+The replay kernel scales every module's instance stream by the fixed ratio
 ``rates[m] / frame_rate`` through a fractional accumulator
 (`repro.serving.replay.expand_fanout`): frame *i*'s instance count at module
 *m* depends only on its position in the module's ready order.  Real video
@@ -10,7 +10,7 @@ cross-sibling load correlation OCTOPINF and Edge-Assisted DNN Serving
 measure dominating tail latency).  :class:`FanoutSpec` selects the regime:
 
 * ``"deterministic"`` (default) — the accumulator, instance-stream-identical
-  to the flat engine path, so the pipelined co-simulation cross-validates
+  to the replay kernel, so the pipelined co-simulation cross-validates
   against the vectorized kernel bit-for-bit.
 * ``"stochastic"`` — per-frame counts ``Poisson(phi_m * B[f, m])`` where the
   *busyness factor* mixes one mean-1 Gamma draw shared by the whole frame
@@ -56,7 +56,7 @@ class AccumulatorFanout:
     ``floor(k * phi) - floor((k - 1) * phi)`` instances — exactly
     `expand_fanout`'s per-position semantics (including its exact-binary
     fast path for half-integer fanouts), so the pipelined co-simulation
-    reproduces the flat engine's instance streams."""
+    reproduces the replay kernel's instance streams."""
 
     def __init__(self, phi: float):
         self.phi = float(phi)

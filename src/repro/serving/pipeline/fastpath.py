@@ -1,9 +1,9 @@
 """Segment fast-path: delegate a quiescent co-simulation to the flat kernel.
 
 PR 3 established (property tests over all apps × arrival processes,
-including ``timeout="budget"``) that the pipelined event loop and the flat
-engine's vectorized per-module replay agree whenever queues are unbounded
-and fanout is deterministic.  This module is that theorem turned into a
+including ``timeout="budget"``) that the pipelined event loop and the
+vectorized per-module replay (`repro.serving.replay`) agree whenever queues
+are unbounded and fanout is deterministic.  This module is that theorem turned into a
 cache: when a segment of the run is *quiescent of everything only the
 event loop can express* —
 
@@ -25,7 +25,7 @@ event-loop re-entry point is the end of stream.
 **The causal order.**  One construct needs care in the flat replay: the
 end-of-stream tail flush with ``timeout=None`` closes a partial batch at
 its last member's ready time — *backdating* service into the past, because
-the flat engine knows module-by-module that the stream has ended.  The
+a module-by-module replay knows that the stream has ended.  The
 event loop only learns that once everything else has drained, so its tail
 flushes (and their downstream cascades) happen strictly after all normal
 events, round by round.  The fast path tracks a *quiescence depth* per
@@ -91,7 +91,7 @@ def run_flat_segment(
 ) -> PipelineResult:
     """Replay one quiescent segment (the whole eligible run) vectorized.
 
-    Module-by-module in topological order — the flat engine's schedule,
+    Module-by-module in topological order — the replay kernel's schedule,
     which the PR-3 ordering argument showed delivers every frame to every
     stage at the same instant and in the same arrival order as the global
     event loop (streams in causal ``(depth, ready, id)`` order; see module

@@ -1,8 +1,8 @@
 """Per-frame results of the pipelined co-simulation + overrun attribution.
 
-The flat engine only reports per-instance module latencies and a per-frame
-end-to-end number; the pipelined core tracks every frame as an entity, so
-this result object can answer the question the latency splitter
+A per-module replay only reports per-instance module latencies and a
+per-frame end-to-end number; the pipelined core tracks every frame as an
+entity, so this result object can answer the question the latency splitter
 (`core.splitter`) actually poses: *which module's budget did a late frame
 blow, and by how much?*
 

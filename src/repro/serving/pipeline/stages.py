@@ -198,10 +198,10 @@ class ModuleStage:
     plan's priced phantom traffic *adaptively*: the stage pads batch
     formation up to that total collect rate (``sum(rate + dummy)``), so a
     phantom is injected only when real traffic has left a gap — the
-    event-interleaved analogue of the flat frontend's pad-to-provisioned
-    injector (`frontend.dummy.phantom_times`).  ``queue_cap`` bounds the
-    number of instances waiting to start service; ``None`` means unbounded
-    (no backpressure — the flat-engine regime).
+    event-interleaved analogue of the pad-to-provisioned injector
+    `frontend.dummy.phantom_times`.  ``queue_cap`` bounds the number of
+    real instances waiting to start service; ``None`` means unbounded (no
+    backpressure).
     """
 
     def __init__(
@@ -273,6 +273,7 @@ class ModuleStage:
         self.keep_spare = False
         self._spare: "int | None" = None
         self.backlog = 0  # instances delivered but not yet started service
+        self.phantoms = 0  # the phantom share of ``backlog``
         # deliveries parked by backpressure: (instance, blocker) where
         # blocker is the (stage, mid) whose outputs they are, or None for
         # ingress arrivals (open-loop frames waiting at the source)
@@ -283,7 +284,16 @@ class ModuleStage:
     # -- capacity ------------------------------------------------------------
     @property
     def has_space(self) -> bool:
-        return self.queue_cap is None or self.backlog < self.queue_cap
+        """Room for one more delivery under ``queue_cap``.
+
+        Only real instances count: phantoms never hold capacity that real
+        deliveries need.  A phantom-only formation buffer arms no deadline,
+        so a stage filled to the cap by phantoms would park its real
+        deliveries for good while a sibling's phantom chain kept the loop
+        alive.  Phantoms stay bounded without the cap: the injector stops
+        at parked deliveries and at queued batches (`service_backlog`).
+        """
+        return self.queue_cap is None or self.backlog - self.phantoms < self.queue_cap
 
     @property
     def service_backlog(self) -> bool:
@@ -462,6 +472,8 @@ class ModuleStage:
         inst.ready = now
         self.delivered += 1
         self.backlog += 1
+        if inst.frame < 0:
+            self.phantoms += 1
         mid = self.dispatcher.assign()
         core = self.cores[mid]
         deadline = core.add(inst, now, inst.real)
@@ -558,6 +570,8 @@ class ModuleStage:
             obs(self.name, core.machine, drawn[0], now)
         self.stats.batches += 1
         self.backlog -= len(members)
+        if self.phantoms:
+            self.phantoms -= sum(1 for i in members if i.frame < 0)
         self.in_service[mid] = members
         if tel is not None:
             d = (
@@ -576,7 +590,8 @@ class ModuleStage:
                 len(reals) * (start - closed_at), len(reals) * d, len(reals),
             )
             tel.queue_depth(now, self.name, self.backlog)
-        push(end, _K_FREE, self.name, (mid,))
+        # the flag tells the owner whether this free can move a real frame
+        push(end, _K_FREE, self.name, (mid, any(i.frame >= 0 for i in members)))
         return True
 
     def fail_machine(self, mid: int, now: float) -> "list[Instance]":
@@ -607,8 +622,10 @@ class ModuleStage:
         # the buffers already emptied and reclaim nothing, but must not
         # re-roll the bookkeeping.
         in_flight = list(self.in_service.pop(mid, ()))
-        members = in_flight + core.fail()
-        self.backlog -= len(members) - len(in_flight)
+        waiting = core.fail()
+        members = in_flight + waiting
+        self.backlog -= len(waiting)
+        self.phantoms -= sum(1 for i in waiting if i.frame < 0)
         self.delivered -= len(members)
         self.machines = [m for m in self.machines if m.mid != mid]
         self.dispatcher.update(self.machines)
@@ -619,6 +636,7 @@ class ModuleStage:
         all_members = self.cores[mid].discard()
         self.backlog -= len(all_members)
         dropped = [i for i in all_members if i.real]
+        self.phantoms -= len(all_members) - len(dropped)
         self.stats.dropped += len(dropped)
         return dropped
 

@@ -130,7 +130,7 @@ def engine_run_reference(
                 raise RuntimeError("cycle in DAG")
         return seen
 
-    def _run_module(m, ready, drop, fanout, finish, st: ModuleStats):
+    def replay_one(m, ready, drop, fanout, finish, st: ModuleStats):
         sched = plan.schedules[m]
         machines = expand_machines(list(sched.allocs))
         order = sorted(range(n_frames), key=lambda i: ready[i])
@@ -178,7 +178,7 @@ def engine_run_reference(
             any(finish_at[p][i] <= 0.0 for p in parents) for i in range(n_frames)
         ] if parents else [False] * n_frames
         fanout = wl.rates[m] / frame_rate
-        _run_module(m, ready, drop, fanout, finish_at[m], stats[m])
+        replay_one(m, ready, drop, fanout, finish_at[m], stats[m])
     sinks = [m for m in wl.app.modules if not wl.app.children(m)]
     e2e = [
         max(finish_at[s][i] for s in sinks) - arrival[i]

@@ -58,20 +58,12 @@ class ModuleReplay:
     finish: np.ndarray  # absolute completion time per request (NaN = dropped)
     assignment: np.ndarray  # serving machine id per request
     batches: dict[int, int]  # executed batches per machine
-    phantom: np.ndarray | None = None  # frontend dummy-request mask (None = none)
     # per-request close time of its batch (NaN = dropped); only when asked
     closed: np.ndarray | None = None
 
     @property
     def done(self) -> np.ndarray:
         return ~np.isnan(self.finish)
-
-    @property
-    def real(self) -> np.ndarray:
-        """Mask of real (non-phantom) requests — the only ones stats count."""
-        if self.phantom is None:
-            return np.ones(self.finish.size, dtype=bool)
-        return ~self.phantom
 
     @property
     def n_batches(self) -> int:
@@ -81,10 +73,10 @@ class ModuleReplay:
         self, ready: np.ndarray, machines: Sequence[Machine]
     ) -> tuple[float, float, float, int]:
         """``(collect, queue, service, n)`` summed over the ``n`` completed
-        real requests (needs ``closed``): Σ (batch close − ready), Σ (batch
-        start − batch close) and Σ (finish − batch start), the column form of
-        the event paths' per-batch feed (`Observability.waits`)."""
-        ok = self.done & self.real
+        requests (needs ``closed``): Σ (batch close − ready), Σ (batch start
+        − batch close) and Σ (finish − batch start), the column form of the
+        event loop's per-batch feed (`Observability.waits`)."""
+        ok = self.done
         lut = np.zeros(max(m.mid for m in machines) + 1)
         for m in machines:
             lut[m.mid] = m.config.duration
@@ -126,8 +118,8 @@ def causal_order(
     value (the max parent finish) when a backdated cascade completion joins
     a normal completion from the sibling branch.  So arrivals order by
     ``(quiescence depth, emit, frame id)``; with no positive depth
-    ``emit == ready`` everywhere and this is exactly the stable ready-sort
-    the flat engine always used.
+    ``emit == ready`` everywhere and this is exactly the plain stable
+    ready-sort.
     """
     if depth is None or not depth.any():
         return np.argsort(ready, kind="stable")
@@ -248,27 +240,17 @@ def _batch_bounds(
     batch: int,
     timeout: float | None,
     tail: str,
-    phantom: np.ndarray | None = None,
 ) -> tuple[np.ndarray, np.ndarray]:
     """Group a machine's sorted ready times into batches.
 
     Returns ``(sizes, g_ready)``: per-batch request counts (consecutive,
     starting at request 0; a dropped tail is simply not covered) and the time
     each batch is handed to the machine.
-
-    ``phantom`` marks frontend dummy requests.  They fill batch slots like
-    real traffic, but a flush deadline is armed only by the batch's first
-    *real* request (the deadline exists to bound real latency), and a
-    leftover batch containing only phantoms is discarded at end of stream
-    instead of executed (the frontend stops injecting when the stream ends).
     """
     n = ready.size
-    has_phantom = phantom is not None and bool(phantom.any())
     if timeout is None:
         n_full, tail_sz = divmod(n, batch)
         flush_tail = bool(tail_sz) and tail == "flush"
-        if flush_tail and has_phantom and bool(phantom[n_full * batch:].all()):
-            flush_tail = False  # phantom-only tail: nothing real to flush for
         ng = n_full + (1 if flush_tail else 0)
         if ng == 0:
             return np.zeros(0, np.int64), np.zeros(0)
@@ -282,47 +264,8 @@ def _batch_bounds(
             # backdated cascade member may sit after the time-max one.  For
             # sorted streams the max IS the last element — bit-identical.
             g_ready = g_ready.astype(np.float64, copy=True)
-            if has_phantom:
-                # ... and only REAL arrivals count (the frontend stops
-                # injecting once the stream ends) — trailing phantoms must
-                # not inflate real tail latency
-                tail_real = np.flatnonzero(~phantom[n_full * batch:])
-                g_ready[-1] = ready[n_full * batch + tail_real].max()
-            else:
-                g_ready[-1] = ready[n_full * batch:].max()
+            g_ready[-1] = ready[n_full * batch:].max()
         return sizes, g_ready
-    if has_phantom:
-        # greedy scan with real-opener deadlines (phantom streams are rare
-        # and short — engine runs — so the O(batches) loop is fine)
-        real_idx = np.flatnonzero(~phantom)
-        sizes_l: list[int] = []
-        gr_l: list[float] = []
-        i = 0
-        ri = 0
-        while i < n:
-            while ri < real_idx.size and real_idx[ri] < i:
-                ri += 1
-            if ri >= real_idx.size:
-                # only phantoms remain: full batches still close by fill
-                # (the machine cannot know), the partial remainder is never
-                # time-flushed and drops at end of stream
-                while i + batch <= n:
-                    sizes_l.append(batch)
-                    gr_l.append(float(ready[i + batch - 1]))
-                    i += batch
-                break
-            deadline = float(ready[real_idx[ri]]) + timeout
-            j = i + batch
-            j_dl = int(np.searchsorted(ready, deadline, side="right"))
-            if j <= j_dl:  # fills before the first real request's deadline
-                r = float(ready[j - 1])
-            else:
-                j = j_dl
-                r = deadline
-            sizes_l.append(j - i)
-            gr_l.append(r)
-            i = j
-        return np.asarray(sizes_l, np.int64), np.asarray(gr_l)
     # deadline semantics: tentative reshape boundaries are valid iff every
     # group's opener deadline covers the group's last member (and the tail's
     # covers the end of stream)
@@ -335,8 +278,8 @@ def _batch_bounds(
             g_ready[-1] = ready[starts[-1]] + timeout
         return ends - starts, g_ready
     # bursty fallback: greedy scan, one iteration per *batch* (not request)
-    sizes_l = []
-    gr_l = []
+    sizes_l: list[int] = []
+    gr_l: list[float] = []
     i = 0
     while i < n:
         deadline = ready[i] + timeout
@@ -360,7 +303,6 @@ def replay_machine(
     *,
     timeout: float | None = None,
     tail: str = "flush",
-    phantom: np.ndarray | None = None,
     closed: np.ndarray | None = None,
 ) -> tuple[np.ndarray, int]:
     """Replay one machine; returns ``(finish, n_batches)``.
@@ -368,9 +310,7 @@ def replay_machine(
     ``ready`` must be in causal order (sorted by time within each quiescence
     depth level — plain sorted when no tail cascades are present; see the
     module docstring).  ``finish[i]`` is the absolute completion time
-    of request ``i`` (NaN when the tail is dropped).  ``phantom`` marks
-    frontend dummy requests (see `_batch_bounds` for their semantics).
-    ``closed`` (an output array of ``ready``'s length, when given) receives
+    of request ``i`` (NaN when the tail is dropped).  ``closed`` (an output array of ``ready``'s length, when given) receives
     each covered request's batch close time.
     """
     if tail not in ("flush", "drop"):
@@ -380,7 +320,7 @@ def replay_machine(
     finish = np.full(n, np.nan)
     if n == 0:
         return finish, 0
-    sizes, g_ready = _batch_bounds(ready, batch, timeout, tail, phantom)
+    sizes, g_ready = _batch_bounds(ready, batch, timeout, tail)
     ng = sizes.size
     if ng == 0:
         return finish, 0
@@ -416,7 +356,6 @@ def replay_module(
     timeout: "float | None | Mapping[int, float]" = None,
     tail: str = "flush",
     method: str = "vectorized",
-    phantom: np.ndarray | None = None,
     with_closed: bool = False,
 ) -> ModuleReplay:
     """Replay one module's machines over a sorted request-ready stream.
@@ -425,26 +364,19 @@ def replay_module(
     ``timeout`` may be one deadline for all machines or a per-machine-id
     mapping (machines with longer service need shorter collection windows to
     meet the same budget).  ``method="events"`` routes through the reference
-    event core instead of the vectorized kernel (identical results; used for
-    cross-validation and whenever real executors are involved).  ``phantom``
-    marks frontend dummy requests: they fill batch slots but never arm flush
-    deadlines or force end-of-stream flushes, and callers exclude them from
-    latency statistics via ``ModuleReplay.real``.  ``with_closed`` (vectorized
-    only) also fills ``ModuleReplay.closed``, each request's batch close
-    time, for the observability layer's wait split.
+    event core instead of the vectorized kernel (identical results; the
+    cross-validation oracle).  ``with_closed`` (vectorized only) also fills
+    ``ModuleReplay.closed``, each request's batch close time, for the
+    observability layer's wait split.
     """
     ready = np.asarray(ready, dtype=np.float64)
     n = ready.size
     assignment = runs_to_assignment(runs, n)
-    if phantom is not None:
-        phantom = np.asarray(phantom, dtype=bool)
-        if phantom.shape != ready.shape:
-            raise ValueError("phantom mask must match the request stream")
     if method == "events":
         finish, batches = simulate_module_events(
-            machines, ready, assignment, timeout=timeout, tail=tail, phantom=phantom
+            machines, ready, assignment, timeout=timeout, tail=tail
         )
-        return ModuleReplay(finish, assignment, batches, phantom)
+        return ModuleReplay(finish, assignment, batches)
     if method != "vectorized":
         raise ValueError(f"unknown method {method!r}")
     finish = np.full(n, np.nan)
@@ -465,13 +397,13 @@ def replay_module(
         c = np.full(idx.size, np.nan) if with_closed else None
         f, nb = replay_machine(
             ready[idx], m.config.batch, m.config.duration, timeout=w, tail=tail,
-            phantom=None if phantom is None else phantom[idx], closed=c,
+            closed=c,
         )
         finish[idx] = f
         if with_closed:
             closed[idx] = c
         batches[m.mid] = nb
-    return ModuleReplay(finish, assignment, batches, phantom, closed)
+    return ModuleReplay(finish, assignment, batches, closed)
 
 
 def fanout_counts(n: int, fanout: float) -> np.ndarray:

@@ -120,16 +120,12 @@ class PoolResult:
         fanout legitimately excluded from the sink) == frames issued."""
         out: dict[str, bool] = {}
         for app, r in self.results.items():
-            n = self.n_frames[app]
             p = r.pipeline
-            if p is not None:
-                resolved = int(
-                    p.completed.sum() + p.shed.sum() + p.dropped.sum()
-                    + p.skipped.sum()
-                )
-                out[app] = resolved == n
-            else:
-                out[app] = r.offered == n
+            resolved = int(
+                p.completed.sum() + p.shed.sum() + p.dropped.sum()
+                + p.skipped.sum()
+            )
+            out[app] = resolved == self.n_frames[app]
         return out
 
     def summary(self) -> str:
@@ -235,7 +231,6 @@ class SharedPool:
         tail: str = "flush",
         frontend=None,
         offered_rates: "Mapping[str, float] | None" = None,
-        pipeline=True,
         control: "ControlLoopConfig | Mapping[str, ControlLoopConfig] | None" = None,
         service_time=None,
         observability=None,
@@ -348,7 +343,6 @@ class SharedPool:
                 offered_rate=(
                     offered_rates.get(app) if offered_rates else None
                 ),
-                pipeline=pipeline,
                 control=app_control,
                 service_time=src,
                 observability=observability,

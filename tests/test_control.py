@@ -186,11 +186,6 @@ def _control(interval, **kw):
 
 
 class TestHotSwap:
-    def test_control_requires_pipeline(self):
-        _, plan = suite_plan("face", 150.0, 2.5)
-        with pytest.raises(ValueError, match="pipeline"):
-            ServingEngine(plan).run(100, 150.0, control=_control(1.0))
-
     def test_control_requires_profiles(self):
         _, plan = suite_plan("face", 150.0, 2.5)
         with pytest.raises(ValueError, match="profiles"):
@@ -320,7 +315,9 @@ class TestFrontendEpochState:
         assert ctrl._rate == 42.0  # operator-pinned policy does not move
         qd = make_admission(QueueDepth(depth=4), "app", 100.0)
         qd.rebind(150.0)
-        assert qd._drain == 150.0
+        assert qd.frame_rate == 150.0
+        # a queue-depth policy has no rate to move: it reads live backlog
+        assert qd.admit_live(0.0, 3) and not qd.admit_live(0.0, 4)
 
     def test_client_backoff_none_is_live_latency(self):
         cfg = ClosedLoopClients(backoff=None, retry_on_shed=True)

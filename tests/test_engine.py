@@ -4,7 +4,7 @@ import pytest
 from repro.core import Planner
 from repro.core import baselines as B
 from repro.core.dispatch import Policy
-from repro.serving import ServingEngine
+from repro.serving import ControlLoopConfig, FaultConfig, ServingEngine
 from repro.workloads import synth_profiles
 from repro.workloads.apps import CAPTION, FACE, TRAFFIC, make_workload
 
@@ -44,3 +44,39 @@ def test_rr_engine_worse_or_equal_latency():
     tc = ServingEngine(plan, policy=Policy.TC).run(1500, 200.0)
     rr = ServingEngine(plan, policy=Policy.RR).run(1500, 200.0)
     assert max(tc.e2e_latencies) <= max(rr.e2e_latencies) + 0.15
+
+
+def _face_plan():
+    plan = Planner(B.HARPAGON).plan(make_workload(FACE, rate=150.0, slo=2.5), PROFILES)
+    assert plan.feasible
+    return plan
+
+
+@pytest.mark.parametrize("what", ["control", "faults", "miss_report"])
+def test_default_run_is_pipelined(what):
+    """A plain ``run()`` serves on the pipelined loop: it takes ``control=``
+    and ``faults=``, and its result carries the per-frame record."""
+    eng = ServingEngine(_face_plan())
+    if what == "control":
+        res = eng.run(
+            400, 150.0, control=ControlLoopConfig(interval=1.0, profiles=PROFILES)
+        )
+        assert res.epochs and len(res.epochs) > 1
+    elif what == "faults":
+        res = eng.run(
+            400, 150.0, faults=FaultConfig(schedule=((0.5, "crash"),))
+        )
+        assert res.faults["injected"] == 1
+    else:
+        res = eng.run(400, 150.0)
+        assert res.miss_report().conserved
+    assert res.pipeline is not None
+    assert len(res.e2e_latencies) + res.shed + res.dropped == res.offered == 400
+
+
+def test_pipeline_false_rejected():
+    """The flat engine is gone: ``pipeline=`` takes True or a config only."""
+    eng = ServingEngine(_face_plan())
+    for bad in (False, None, 1):
+        with pytest.raises(TypeError, match="pipeline"):
+            eng.run(10, 150.0, pipeline=bad)

@@ -158,25 +158,3 @@ def test_tail_drop_vs_flush_without_timeout():
     assert nb_drop == 1 and nb_flush == 2
     # seed-engine semantics: tail executes at its last arrival
     assert f_flush[8:] == pytest.approx(max(ready[9], f_flush[0]) + 0.1)
-
-
-def test_event_core_executor_plumbing():
-    """A constant-duration executor must reproduce the profiled-duration
-    virtual-time replay bit for bit."""
-    cfg = Config(4, 0.07)
-    m = Machine(0, cfg, cfg.throughput)
-    ready = make_arrivals("poisson", 40, cfg.throughput, seed=9)
-    assignment = np.zeros(40, dtype=int)
-    calls = []
-
-    def executor(machine, group):
-        calls.append((machine.mid, group))
-        return 0.07
-
-    f_ex, b_ex = simulate_module_events(
-        [m], ready, assignment, timeout=0.5, executor=executor
-    )
-    f_vt, b_vt = simulate_module_events([m], ready, assignment, timeout=0.5)
-    np.testing.assert_allclose(f_ex, f_vt, atol=1e-12)
-    assert len(calls) == b_ex[0] == b_vt[0]
-    assert all(g <= cfg.batch for _, g in calls)

@@ -2,7 +2,7 @@
 detection, and graceful-degradation recovery.
 
 Covers: `FaultConfig` validation and the disabled-injector contract (a
-disabled config is bit-exact with ``faults=None`` on the flat, pipelined,
+disabled config is bit-exact with ``faults=None`` on the pipelined,
 control-plane, and tenancy paths), frame conservation
 ``completed + shed + dropped == offered`` under randomized seeded fault
 schedules with every miss classified into exactly one forensics cause,
@@ -83,13 +83,6 @@ class TestFaultConfig:
     def test_rejects_bad_knobs(self, kw):
         with pytest.raises(ValueError):
             FaultConfig(**kw)
-
-    def test_engine_rejects_enabled_faults_off_pipeline(self):
-        plan = suite_plan("traffic", 100.0, 2.0)
-        with pytest.raises(ValueError, match="pipeline"):
-            ServingEngine(plan).run(
-                100, 100.0, faults=FaultConfig(schedule=((0.5, "crash"),))
-            )
 
     def test_engine_rejects_non_config(self):
         plan = suite_plan("traffic", 100.0, 2.0)
@@ -185,8 +178,8 @@ class TestFaultOffBitExact:
             a: suite_plan(a, r, s)
             for a, r, s in (("traffic", 100.0, 2.0), ("pose", 60.0, 3.0))
         }
-        base = SharedPool(plans).run(300, pipeline=True)
-        off = SharedPool(plans).run(300, pipeline=True, faults=FaultConfig())
+        base = SharedPool(plans).run(300)
+        off = SharedPool(plans).run(300, faults=FaultConfig())
         for a in plans:
             assert np.array_equal(
                 base.results[a].pipeline.e2e,
@@ -434,7 +427,7 @@ class TestDeviceLoss:
         }
         pool = SharedPool(plans)
         res = pool.run(
-            300, pipeline=True,
+            300,
             faults=FaultConfig(
                 schedule=((0.8, "device_loss"),), seed=5, detect_k=2.0
             ),

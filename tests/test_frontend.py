@@ -8,13 +8,11 @@ flow, admission control bounds p99 under MMPP overload, closed-loop clients
 self-throttle, and frame accounting conserves: completed + shed + dropped
 == offered.
 """
-import random
-
 import numpy as np
 import pytest
 
 from repro.core.dag import AppDAG, Leaf, Workload
-from repro.core.dispatch import Machine, Policy, dispatch_runs, expand_machines
+from repro.core.dispatch import Policy, expand_machines
 from repro.core.harpagon import Plan, PlannerOptions
 from repro.core.profiles import Config, ModuleProfile
 from repro.core.residual import schedule_module
@@ -28,9 +26,7 @@ from repro.serving.frontend import (
     TokenBucket,
     make_admission,
 )
-from repro.serving.frontend.clients import closed_loop_ingress
-from repro.serving.frontend.dummy import merge_phantoms, phantom_times
-from repro.serving.replay import replay_module
+from repro.serving.frontend.dummy import phantom_times
 
 
 def single_module_plan(
@@ -158,64 +154,6 @@ class TestDummyStreaming:
         ph = phantom_times(ready, 100.0)
         span = ready[-1] - ready[0]
         assert ph.size == pytest.approx(50.0 * span, abs=1.5)
-        merged, mask = merge_phantoms(ready, ph)
-        assert merged.size == ready.size + ph.size
-        assert int(mask.sum()) == ph.size
-        assert np.all(np.diff(merged) >= 0)
-        # stable merge: real sub-stream keeps its order and values
-        np.testing.assert_array_equal(merged[~mask], ready)
-
-
-def _random_machines(rng: random.Random) -> list[Machine]:
-    machines = []
-    for mid in range(rng.randint(1, 3)):
-        b = 2 ** rng.randint(0, 4)
-        d = round(rng.uniform(0.02, 0.4), 6)
-        cfg = Config(b, d, "hw", rng.choice([1.0, 1.35]))
-        machines.append(Machine(mid, cfg, cfg.throughput * rng.uniform(0.3, 1.0)))
-    return machines
-
-
-def test_trailing_phantoms_do_not_inflate_tail_latency():
-    """End-of-stream flush (timeout=None) happens at the last REAL arrival:
-    phantoms injected after the last real request must not delay it."""
-    cfg = Config(8, 0.1)
-    machines = [Machine(0, cfg, cfg.throughput)]
-    ready = np.array([0.0, 0.05, 0.10, 0.4, 0.8, 1.2])
-    phantom = np.array([False, False, False, True, True, True])
-    runs = [(0, 6)]
-    for method in ("vectorized", "events"):
-        rep = replay_module(machines, ready, runs, phantom=phantom, method=method)
-        # one partial batch, flushed at the last real arrival (0.10) + service
-        assert rep.n_batches == 1, method
-        np.testing.assert_allclose(rep.finish, 0.10 + 0.1, atol=1e-12)
-
-
-@pytest.mark.parametrize("kind", ["uniform", "poisson", "mmpp"])
-def test_kernel_matches_event_core_with_phantoms(kind):
-    """Phantom semantics (fill slots, real-opener deadlines, phantom-only
-    leftovers dropped) must agree between the vectorized kernel and the
-    event core."""
-    rng = random.Random(hash(kind) & 0xFFFF)
-    for trial in range(8):
-        machines = _random_machines(rng)
-        n = rng.randint(40, 300)
-        rate = sum(m.rate for m in machines)
-        real = make_arrivals(kind, n, rate, seed=trial)
-        ph = phantom_times(real, rate * rng.uniform(1.1, 2.5))
-        ready, phantom = merge_phantoms(real, ph)
-        runs = dispatch_runs(machines, ready.size, Policy.TC)
-        timeout = rng.choice([None, 0.05, 0.5])
-        vec = replay_module(machines, ready, runs, timeout=timeout, phantom=phantom)
-        ev = replay_module(
-            machines, ready, runs, timeout=timeout, phantom=phantom, method="events"
-        )
-        assert vec.batches == ev.batches, (trial, timeout)
-        np.testing.assert_allclose(
-            vec.finish, ev.finish, rtol=0, atol=1e-9, equal_nan=True
-        )
-        # phantom mask rides on the result for stats exclusion
-        np.testing.assert_array_equal(vec.real, ~phantom)
 
 
 # ---------------------------------------------------------- admission control
@@ -223,27 +161,45 @@ def test_kernel_matches_event_core_with_phantoms(kind):
 
 class TestAdmission:
     def test_token_bucket_rate_bound(self):
-        """Admitted traffic over the run is bounded by rate * span + burst."""
+        """Admitted traffic over the run is bounded by rate * span + burst;
+        `admit_live` delegates a token bucket to the time-based `admit`."""
         ctrl = AdmissionController(TokenBucket(rate=50.0, burst=5.0), 50.0)
+        live = AdmissionController(TokenBucket(rate=50.0, burst=5.0), 50.0)
         arrivals = make_arrivals("poisson", 2000, 100.0, seed=1)
-        shed = ctrl.shed_stream(arrivals)
+        admitted = np.array([ctrl.admit(float(t)) for t in arrivals])
         span = arrivals[-1] - arrivals[0]
-        admitted = int((~shed).sum())
-        assert admitted <= 50.0 * span + 5.0 + 1
-        assert ctrl.admitted == admitted and ctrl.shed == int(shed.sum())
+        assert int(admitted.sum()) <= 50.0 * span + 5.0 + 1
+        assert ctrl.admitted == int(admitted.sum())
+        assert ctrl.shed == int((~admitted).sum())
+        # the backlog argument never moves a token bucket's decision
+        np.testing.assert_array_equal(
+            [live.admit_live(float(t), 10**6) for t in arrivals], admitted
+        )
 
     def test_queue_depth_bounds_backlog(self):
-        """No admitted frame ever waits behind more than `depth` frames."""
-        ctrl = AdmissionController(QueueDepth(depth=4, drain_rate=10.0), 10.0)
+        """No admitted frame ever waits behind `depth` frames: a scripted
+        source backlog, drained FIFO at 10 frames/s, stays within depth."""
+        ctrl = AdmissionController(QueueDepth(depth=4), 10.0)
+        with pytest.raises(TypeError):
+            ctrl.admit(0.0)  # the live backlog is the only queue-depth signal
         arrivals = make_arrivals("mmpp", 500, 20.0, seed=2)
-        shed = ctrl.shed_stream(arrivals)
-        assert shed.any() and (~shed).any()
-        # virtual completion of admitted frame k is at most (depth+1)/drain
-        # after its arrival
+        done: list[float] = []  # service completions of waiting frames
         free = 0.0
-        for t in arrivals[~shed]:
-            free = max(free, t) + 0.1
-            assert free - t <= (4 + 1) * 0.1 + 1e-9
+        admitted = []
+        for t in arrivals:
+            done = [d for d in done if d > t]
+            ok = ctrl.admit_live(float(t), len(done))
+            admitted.append(ok)
+            if ok:
+                free = max(free, t) + 0.1
+                done.append(free)
+                # completion at most (depth + 1) service slots after arrival
+                assert free - t <= (4 + 1) * 0.1 + 1e-9
+            assert len(done) <= 4
+        admitted = np.asarray(admitted)
+        assert admitted.any() and (~admitted).any()
+        assert ctrl.admitted == int(admitted.sum())
+        assert ctrl.shed == int((~admitted).sum())
 
     def test_admission_bounds_p99_under_mmpp_overload(self):
         """Acceptance: at >= provisioned rate under MMPP the uncontrolled
@@ -289,30 +245,6 @@ class TestAdmission:
 
 
 class TestClosedLoop:
-    def test_in_flight_bound_serializes_issues(self):
-        """One client, one slot: every issue waits for the previous
-        completion plus the (constant) think time."""
-        cfg = ClosedLoopClients(
-            n_clients=1, max_in_flight=1, think_time=0.05, think_dist="const"
-        )
-        lat = np.full(50, 0.2)
-        issue, shed, attempts = closed_loop_ingress(cfg, 50, 10.0, lat)
-        assert not shed.any() and attempts == 50
-        np.testing.assert_allclose(np.diff(issue), 0.25, atol=1e-12)
-
-    def test_retry_on_shed_conserves_frames(self):
-        cfg = ClosedLoopClients(
-            n_clients=4, retry_on_shed=True, max_retries=2, backoff=0.01
-        )
-        ctrl = AdmissionController(TokenBucket(rate=20.0, burst=2.0), 20.0)
-        lat = np.full(300, 0.05)
-        issue, shed, attempts = closed_loop_ingress(
-            cfg, 300, 100.0, lat, admission=ctrl, seed=3
-        )
-        assert attempts >= 300  # retries add attempts
-        assert int(shed.sum()) + int((~shed).sum()) == 300
-        assert np.all(np.diff(issue[~shed]) >= -1e-9) or True  # times monotone per slot
-
     def test_engine_closed_loop_self_throttles(self):
         """Closed-loop offered rate adapts to service latency: with few
         clients the engine serves everything within SLO even though the

@@ -2,8 +2,8 @@
 registry, and SLO-miss forensics.
 
 Pins the layer's load-bearing properties: results are BIT-identical with
-observability on, off, or sampled (flat, pipelined, and control-plane
-paths); every miss report conserves — cause counts sum exactly to
+observability on, off, or sampled (fast path, event loop, and
+control-plane paths); every miss report conserves — cause counts sum exactly to
 ``offered - completed-in-SLO`` — across apps x arrivals x admission x
 control epochs, with each miss carrying exactly one cause; the Perfetto
 export is valid trace-event JSON; the trace ring buffer and deterministic
@@ -110,7 +110,7 @@ class TestBitExact:
         off = eng.run(800, 150.0, **kw)
         on = eng.run(800, 150.0, observability=True, **kw)
         assert result_key(off) == result_key(on)
-        # flat-path ingress sheds reach the telemetry (admission.obs hook)
+        # queue-depth ingress sheds reach the telemetry (admission.obs hook)
         assert on.shed > 0
         assert sum(
             1 for ev in on.trace.events() if ev[4] == "shed"
@@ -197,12 +197,6 @@ class TestConservation:
         assert n_shed > 0
         assert rep.counts.get("admission_shed", 0) == n_shed
         assert rep.conserved
-
-    def test_miss_report_requires_pipeline(self):
-        plan = suite_plan("face", 150.0, 2.5)
-        res = ServingEngine(plan).run(200, 150.0)
-        with pytest.raises(ValueError, match="pipeline"):
-            res.miss_report()
 
 
 # ------------------------------------------------ trace recorder mechanics
@@ -313,7 +307,7 @@ def _waits(res, module):
 WAIT_PATHS = {
     "reference": dict(pipeline=PipelineConfig(reference=True)),
     "fast_path": dict(pipeline=True),
-    "flat": dict(pipeline=False),
+    "general": dict(pipeline=PipelineConfig(fast_path=False)),
 }
 
 
@@ -356,7 +350,7 @@ class TestWaits:
                     )
                 )
         ref = runs["reference"]
-        for name in ("fast_path", "flat"):
+        for name in ("fast_path", "general"):
             for m in ref.module_stats:
                 a, b = _waits(ref, m), _waits(runs[name], m)
                 assert a["waited"] == b["waited"], (name, m)
@@ -379,12 +373,12 @@ class TestWaits:
 
 
 class TestHostSpans:
-    @pytest.mark.parametrize("pipeline", [True, False])
-    def test_gc_hook_is_passive_and_removed(self, pipeline):
+    @pytest.mark.parametrize("reference", [True, False])
+    def test_gc_hook_is_passive_and_removed(self, reference):
         plan = suite_plan("face", 150.0, 2.5)
         eng = ServingEngine(plan)
         kw = dict(arrivals="mmpp", seed=0, timeout="budget",
-                  pipeline=pipeline)
+                  pipeline=PipelineConfig(reference=reference))
         hooks = list(gc.callbacks)
         threshold = gc.get_threshold()
         gc.set_threshold(50)  # many collections inside the run

@@ -1,17 +1,15 @@
 """Pipelined serving core: per-frame DAG co-simulation (ISSUE-3 acceptance).
 
-Covers: golden equivalence of the co-simulation against the flat engine's
-vectorized kernel on multi-stage DAGs (the kernel-vs-event-core
-cross-validation extended through the DAG), the uniform-arrivals
-mean-vs-analytic-WCL-sum acceptance bound, the splitter-budget property
-(feasible `split_lc` budgets hold end-to-end; budget-overrun attribution
-sums exactly to the end-to-end overrun), backpressure under bounded queues,
-correlated per-frame stochastic fanout, event-interleaved closed-loop
-clients agreeing with the deprecated fixed-point formulation, and the
-per-rank `timeout="budget"` fill-time floor.
+Covers: golden equivalence of the default path (the vectorized kernel, via
+the segment fast path) against the event-by-event oracle loop on
+multi-stage DAGs (the kernel-vs-event-core cross-validation extended
+through the DAG), the uniform-arrivals mean-vs-analytic-WCL-sum acceptance
+bound, the splitter-budget property (feasible `split_lc` budgets hold
+end-to-end; budget-overrun attribution sums exactly to the end-to-end
+overrun), backpressure under bounded queues, correlated per-frame
+stochastic fanout, event-interleaved closed-loop clients obeying Little's
+law, and the per-rank `timeout="budget"` fill-time floor.
 """
-import warnings
-
 import numpy as np
 import pytest
 
@@ -35,6 +33,7 @@ from repro.workloads import synth_profiles, synth_workloads
 from repro.workloads.apps import ACTDET, CAPTION, FACE, FANOUT, TRAFFIC, make_workload
 
 PROFILES = synth_profiles()
+REF = PipelineConfig(reference=True)
 
 
 def chain_plan(specs, rate: float, slo: float, fanouts=None) -> Plan:
@@ -65,17 +64,19 @@ def suite_plan(app, rate, slo):
 
 
 class TestGoldenEquivalence:
-    """With unbounded queues and deterministic fanout the co-simulation must
-    reproduce the flat engine (vectorized kernel) bit-for-bit: same instance
-    streams, same batch boundaries, same per-frame e2e — the kernel-vs-
-    event-core cross-validation extended through multi-stage DAGs."""
+    """With unbounded queues and deterministic fanout the default run
+    delegates to the vectorized kernel, which must reproduce the
+    event-by-event oracle loop (``PipelineConfig(reference=True)``): same
+    instance streams, same batch boundaries, same per-frame e2e — the
+    kernel-vs-event-core cross-validation extended through multi-stage
+    DAGs."""
 
     @pytest.mark.parametrize("kind", ["uniform", "poisson", "mmpp"])
     def test_two_stage_dag_matches_kernel(self, kind):
         plan = suite_plan(FACE, 150.0, 2.5)
         eng = ServingEngine(plan)
-        flat = eng.run(600, 150.0, arrivals=kind, seed=5)
-        pipe = eng.run(600, 150.0, arrivals=kind, seed=5, pipeline=True)
+        flat = eng.run(600, 150.0, arrivals=kind, seed=5, pipeline=REF)
+        pipe = eng.run(600, 150.0, arrivals=kind, seed=5)
         np.testing.assert_allclose(
             np.asarray(pipe.e2e_latencies), np.asarray(flat.e2e_latencies), atol=1e-9
         )
@@ -95,8 +96,8 @@ class TestGoldenEquivalence:
         """Parallel branches (traffic/actdet) and fanout < 1 (caption)."""
         plan = suite_plan(app, rate, slo)
         eng = ServingEngine(plan)
-        flat = eng.run(500, rate, arrivals="mmpp", seed=2)
-        pipe = eng.run(500, rate, arrivals="mmpp", seed=2, pipeline=True)
+        flat = eng.run(500, rate, arrivals="mmpp", seed=2, pipeline=REF)
+        pipe = eng.run(500, rate, arrivals="mmpp", seed=2)
         assert len(pipe.e2e_latencies) == len(flat.e2e_latencies)
         np.testing.assert_allclose(
             np.asarray(pipe.e2e_latencies), np.asarray(flat.e2e_latencies), atol=1e-9
@@ -106,10 +107,10 @@ class TestGoldenEquivalence:
     def test_budget_timeout_matches_kernel(self):
         plan = suite_plan(FACE, 150.0, 2.5)
         eng = ServingEngine(plan)
-        flat = eng.run(500, 150.0, arrivals="poisson", seed=1, timeout="budget")
-        pipe = eng.run(
-            500, 150.0, arrivals="poisson", seed=1, timeout="budget", pipeline=True
+        flat = eng.run(
+            500, 150.0, arrivals="poisson", seed=1, timeout="budget", pipeline=REF
         )
+        pipe = eng.run(500, 150.0, arrivals="poisson", seed=1, timeout="budget")
         np.testing.assert_allclose(
             np.asarray(pipe.e2e_latencies), np.asarray(flat.e2e_latencies), atol=1e-9
         )
@@ -457,6 +458,54 @@ class TestPipelineDummyStreaming:
         injected = sum(s.phantom for s in res.module_stats.values())
         assert injected <= 8  # at most start-up slack, not a stream
 
+    def test_phantoms_hold_no_queue_capacity(self):
+        """A bounded stage whose formation buffers hold only phantoms (which
+        arm no deadline) still takes real deliveries; were phantoms to
+        count against ``queue_cap``, real deliveries would park for good,
+        their upstream machines stay blocked, and the frames drop."""
+        from repro.core.dispatch import Machine
+        from repro.serving.pipeline import Instance, ModuleStage
+
+        c = Config(8, 0.1)
+        machines = [Machine(0, c, c.throughput), Machine(1, c, c.throughput)]
+        st = ModuleStage("M", machines, Policy.RR, queue_cap=8, phantom_target=1.0)
+        for _ in range(8):  # round-robin: four phantoms a buffer, none full
+            st.deliver(Instance(-1), 0.0, lambda *e: None)
+        assert st.backlog == 8 and not any(k.full for k in st.cores.values())
+        assert st.has_space
+        # end to end: the bounded, dummy-streaming run that once wedged
+        eng = ServingEngine(suite_plan(TRAFFIC, 100.0, 2.0))
+        res = eng.run(
+            160, 100.0, arrivals="mmpp", seed=1, timeout="budget",
+            frontend=FrontendConfig(dummies=True),
+            pipeline=PipelineConfig(queue_cap=6),
+        )
+        assert res.dropped == 0 and len(res.e2e_latencies) == 160
+
+    def test_trailing_phantoms_do_not_inflate_tail_latency(self):
+        """End of stream with no flush deadline: the injector stops with the
+        stage's real stream, so the tail batch flushes at its last REAL
+        arrival — phantoms must not fill it at the provisioned rate (which
+        here would hold the three frames for seconds)."""
+        from repro.core.dispatch import Alloc
+        from repro.core.residual import ModuleSchedule
+
+        c = Config(8, 0.1)
+        a = Alloc(c, 1.0, 2.0, dummy=5.0)  # collect target: 7 req/s
+        s = ModuleSchedule("M", a.rate, 0.0, 0.5, (a,), Policy.TC)
+        wl = Workload(AppDAG("app", Leaf("M")), {"M": a.rate}, 1.0)
+        plan = Plan(wl, PlannerOptions(), {"M": s}, True, 0.0)
+        for cfg in (True, REF):
+            res = ServingEngine(plan).run(
+                3, a.rate, arrivals=np.array([0.0, 0.05, 0.10]),
+                frontend=FrontendConfig(dummies=True), pipeline=cfg,
+            )
+            # one partial batch, flushed at the last real arrival (0.10)
+            assert res.module_stats["M"].batches == 1
+            np.testing.assert_allclose(
+                res.pipeline.finish["M"], 0.10 + 0.1, atol=1e-12
+            )
+
 
 # ------------------------------------------------- event-interleaved clients
 
@@ -473,33 +522,41 @@ class TestInterleavedClients:
             100.0, 0.5,
         )
 
-    def test_fixed_point_shim_deprecated(self):
-        eng = ServingEngine(self._plan(batched=False))
-        fe = FrontendConfig(clients=ClosedLoopClients(n_clients=4))
-        with pytest.warns(DeprecationWarning):
-            eng.run(50, 100.0, frontend=fe)
-
     @pytest.mark.parametrize("batched", [False, True])
-    def test_agrees_with_fixed_point_on_uniform_pacing(self, batched):
-        """Satellite acceptance: the deprecated fixed-point formulation and
-        the event-interleaved loop agree within tolerance when the closed
-        loop paces uniformly (constant think, deterministic service)."""
+    def test_closed_loop_obeys_littles_law(self, batched):
+        """Every slot alternates one frame's e2e latency and one think, so
+        in steady state the issue rate is ``n_clients / (think + mean
+        e2e)`` — the latency-oracle model, here an assertion on the
+        event-interleaved loop.  Read between the first and third quartiles
+        of issue time, away from ramp-up and drain."""
         plan = self._plan(batched)
-        eng = ServingEngine(plan)
-        n_clients = 80 if batched else 4
+        n_clients, think = (80, 0.2) if batched else (4, 0.05)
         fe = FrontendConfig(clients=ClosedLoopClients(
-            n_clients=n_clients, think_time=0.2 if batched else 0.05,
-            think_dist="const", max_iters=8,
+            n_clients=n_clients, think_time=think, think_dist="const",
         ))
-        with warnings.catch_warnings():
-            warnings.simplefilter("ignore", DeprecationWarning)
-            fp = eng.run(400, 80.0 if batched else 100.0, frontend=fe)
-        il = eng.run(400, 80.0 if batched else 100.0, frontend=fe, pipeline=True)
-        assert il.offered == fp.offered == 400
-        assert np.mean(il.e2e_latencies) == pytest.approx(
-            np.mean(fp.e2e_latencies), rel=0.05
+        res = ServingEngine(plan).run(
+            400, 80.0 if batched else 100.0, frontend=fe
         )
-        assert il.attainment == pytest.approx(fp.attainment, abs=0.05)
+        assert res.offered == 400 and res.shed == 0 and res.dropped == 0
+        issue = res.pipeline.issue
+        t = np.sort(issue)
+        lo, hi = len(t) // 4, 3 * len(t) // 4
+        rate = (hi - lo) / (t[hi] - t[lo])
+        mid = (issue >= t[lo]) & (issue < t[hi])
+        e2e = float(np.mean(res.pipeline.e2e[mid]))
+        assert rate == pytest.approx(n_clients / (think + e2e), rel=0.02)
+
+    def test_in_flight_bound_serializes_issues(self):
+        """One client, one slot: every issue waits for the previous
+        completion plus the (constant) think time."""
+        plan = chain_plan([("A", [Config(1, 0.2)], 0.5)], 4.0, 1.0)
+        fe = FrontendConfig(clients=ClosedLoopClients(
+            n_clients=1, max_in_flight=1, think_time=0.05, think_dist="const",
+        ))
+        res = ServingEngine(plan).run(50, 4.0, frontend=fe)
+        assert res.shed == 0 and res.attempts == 50
+        np.testing.assert_allclose(res.pipeline.e2e, 0.2, atol=1e-12)
+        np.testing.assert_allclose(np.diff(res.pipeline.issue), 0.25, atol=1e-12)
 
     def test_self_throttle_under_tiny_plan(self):
         """Few clients against a slow plan: the interleaved loop serves

@@ -27,7 +27,7 @@ def cache_in(tmp_path, monkeypatch):
 def test_serve_real_smoke_pipeline_end_to_end(cache_in):
     n = 40
     run = serve.main([
-        "--arch", "smollm-360m,gemma3-1b", "--real", "--smoke", "--pipeline",
+        "--arch", "smollm-360m,gemma3-1b", "--real", "--smoke",
         "--requests", str(n), "--seq", "16", "--rate", "50",
     ])
     res = run.result
@@ -46,7 +46,7 @@ def test_serve_real_smoke_moe_returns_routes(cache_in):
     """The MLA + sigmoid-routed MoE module on the served path: each forward
     returns its logits and the routes the timed call itself produced."""
     run = serve.main([
-        "--arch", "moonlight-16b-a3b", "--real", "--smoke", "--pipeline",
+        "--arch", "moonlight-16b-a3b", "--real", "--smoke",
         "--requests", "40", "--seq", "16", "--rate", "50",
     ])
     res = run.result

@@ -2,7 +2,7 @@
 (ISSUE-6): the simulator-to-serving bridge.
 
 Covers: the analytic backend's bit-exactness (source unset vs an explicit
-`AnalyticServiceTime` — flat and pipelined), trace-backend determinism under
+`AnalyticServiceTime` — fast path and event loop), trace-backend determinism under
 a fixed seed (and divergence from analytic once samples differ), the trace
 key ladder ((module, batch, hardware) before (module, batch) before module),
 live-backend measurement/caching/`to_trace` freezing, `resolve_service_time`
@@ -24,6 +24,7 @@ from repro.serving import (
     TraceServiceTime,
     resolve_service_time,
 )
+from repro.serving.pipeline import PipelineConfig
 from repro.workloads import synth_profiles
 from repro.workloads.apps import app_by_name, make_workload
 
@@ -45,11 +46,14 @@ def _machine(module="m", batch=8, duration=0.05, hardware="tpu-v4"):
 class TestAnalyticBitExact:
     """service_time=None and an explicit analytic source are the same run."""
 
-    @pytest.mark.parametrize("pipeline", [False, True])
-    def test_bit_exact(self, pipeline):
+    @pytest.mark.parametrize("reference", [False, True])
+    def test_bit_exact(self, reference):
         plan = _face_plan()
         eng = ServingEngine(plan)
-        kw = dict(arrivals="poisson", seed=3, pipeline=pipeline)
+        kw = dict(
+            arrivals="poisson", seed=3,
+            pipeline=PipelineConfig(reference=reference),
+        )
         base = eng.run(2000, 150.0, **kw)
         explicit = eng.run(2000, 150.0, service_time=AnalyticServiceTime(), **kw)
         assert np.array_equal(
